@@ -50,7 +50,9 @@ def bench_spec():
     if SMOKE:
         return True, 2, 3, 1 << 14, 2
     if FULL:
-        return False, 3, 4, 1 << 18, 8
+        # m = 2: at published widths one v5e holds the EF state and the
+        # gradient block of two devices, not four (ROADMAP R2)
+        return False, 3, 2, 1 << 18, 8
     return True, 3, 4, 1 << 15, 4
 
 
@@ -81,7 +83,8 @@ def main(collect: Optional[list] = None, out_path: str = OUT_PATH) -> Dict:
     fed = CompiledFedLLM(arch, tc, ota, m=m, batch=batch, seq_len=seq_len,
                          chunk_size=chunk_size, seed=0)
     keys = round_keys(rounds + 1, 0)
-    seg = jax.jit(lambda k, c, t: fed.run_segment({}, k, None, c, t))
+    seg = jax.jit(lambda k, c, t: fed.run_segment({}, k, None, c, t),
+                  donate_argnums=(1,))
     carry = fed.carry0()
     t0 = time.time()
     carry, _ = jax.block_until_ready(seg(keys[:1], carry, jnp.int32(0)))

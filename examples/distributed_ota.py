@@ -17,9 +17,10 @@ import jax.numpy as jnp                                        # noqa: E402
 from repro.configs import get_config                           # noqa: E402
 from repro.configs.base import OTAConfig, TrainConfig          # noqa: E402
 from repro.data.synthetic import TokenStream                   # noqa: E402
+from repro.launch.mesh import auto_mesh                        # noqa: E402
 from repro.train.trainer import make_train_step                # noqa: E402
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 arch = get_config("smollm_360m").reduced()
 train_cfg = TrainConfig(optimizer="adam", lr=5e-3, warmup_steps=5,
                         total_steps=60, compute_dtype="float32", remat=True)

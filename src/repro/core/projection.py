@@ -105,11 +105,11 @@ class BlockedProjector:
 
     @property
     def kernel_nb_tile(self) -> int:
-        """Blocks batched per Pallas program (VMEM-budget analogue of the
-        HBM-budget ``chunk_blocks``); the kernel wrappers clamp further."""
-        from repro.kernels.ota_project import VMEM_TILE_BYTES
+        """Blocks per program of the fused AMP kernel (the VMEM analogue of
+        the HBM-budget ``chunk_blocks``: their A stays resident)."""
+        from repro.kernels.amp_fused import AMP_A_BYTES
         return _chunk_blocks_for(self.s_block, self.block_size,
-                                 budget_bytes=VMEM_TILE_BYTES)
+                                 budget_bytes=AMP_A_BYTES)
 
     @property
     def d_pad(self) -> int:

@@ -112,19 +112,12 @@ class MACContext:
         return dataclasses.replace(self, p_factor=p_factor)
 
 
-def axis_size(ax: str) -> int:
-    """Static size of a manual mesh axis (portable across jax versions)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)
-
-
 def shard_info(shard_axes: Sequence[str]):
     """(shard_idx, n_shards) of the calling device along the manual axes."""
     n_shards = 1
     shard_idx = jnp.zeros((), jnp.uint32)
     for ax in shard_axes:
-        sz = axis_size(ax)
+        sz = jax.lax.axis_size(ax)
         shard_idx = shard_idx * sz + jax.lax.axis_index(ax).astype(jnp.uint32)
         n_shards *= sz
     return shard_idx, n_shards
@@ -626,7 +619,7 @@ class ADSGDScheme(Scheme):
             n_rows = 1
             row_idx = jnp.zeros((), jnp.int32)
             for ax in ctx.device_axes:
-                sz = axis_size(ax)
+                sz = jax.lax.axis_size(ax)
                 row_idx = row_idx * sz + jax.lax.axis_index(ax)
                 n_rows *= sz
             nb = y_norm.shape[0]

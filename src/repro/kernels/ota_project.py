@@ -11,9 +11,13 @@ TPU adaptation notes (docs/DESIGN.md §4): each grid program batches
 (MXU) instead of a per-block matvec; the VPU generates the next A tile's
 entries from integer hashes while the MXU consumes the previous one
 (software pipelining by the Mosaic compiler); Rademacher entries (one hash
-+ sign) instead of Box-Muller Gaussians.  The seed arrives through SMEM as
-a *traced* uint32 scalar, so the shard-folded seeds of the fully-sharded
-slice driver (core/distributed.py) lower through the same kernels.
++ sign) instead of Box-Muller Gaussians.  Tiles follow the TPU block rule
+(8 blocks per program, lane tiles of 128) and the contracted dim is split
+over the grid's last axis, summed into the resident output block, so one
+A tile stays a few MiB at the published c = 4096, s_block = 1024.  The
+seed arrives through SMEM as a *traced* uint32 scalar, so the shard-folded
+seeds of the fully-sharded slice driver (core/distributed.py) lower
+through the same kernels.
 
 Kernels are validated in interpret mode against kernels/ref.py.
 """
@@ -28,9 +32,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import _GOLDEN, _M1, _M2
 
-#: VMEM budget for one program's A tile (bytes); actual VMEM is ~16 MiB/core,
-#: leave room for x/y blocks, the double-buffered next tile and AMP carries.
-VMEM_TILE_BYTES = 4 << 20
+#: VMEM budget for one program's generated A tile (bytes).  The hash
+#: temporaries of the tile live in VMEM beside it, so the tile stays well
+#: under the 16 MiB scoped-VMEM default of a v5e core.
+VMEM_TILE_BYTES = 2 << 20
+
+#: TPU block rule: the last two dims of a block are multiples of
+#: (_SUBLANE, _LANE) or span the whole array dim.
+_SUBLANE, _LANE = 8, 128
 
 
 # ---------------------------------------------------------------------------
@@ -48,12 +57,21 @@ def _splitmix32(x):
     return x
 
 
+def _u32_to_f32(h):
+    """``h.astype(float32)`` without the unsigned cast Mosaic lacks: both
+    16-bit halves convert exactly, and the one rounding of their exact sum
+    is the correctly rounded conversion of ``h``."""
+    hi = (h >> 16).astype(jnp.int32).astype(jnp.float32)
+    lo = (h & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
 def _tile_A(seed, block0, row0, col0, nb_tile: int, r_tile: int, c_tile: int,
             s_block: int, rademacher: bool):
     """Generate the (nb_tile, r_tile, c_tile) stacked-A tile whose first
     block is ``block0``, starting at entry (row0, col0) of each block.
 
-    ``seed``/``block0`` may be traced uint32 scalars (SMEM-prefetched)."""
+    ``seed``/``block0``/``row0``/``col0`` may be traced uint32 scalars."""
     shape = (nb_tile, r_tile, c_tile)
     blocks = block0 + jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
     rows = row0 + jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
@@ -63,45 +81,54 @@ def _tile_A(seed, block0, row0, col0, nb_tile: int, r_tile: int, c_tile: int,
     h = _splitmix32(h ^ cols)
     scale = jnp.float32(1.0 / (s_block ** 0.5))
     if rademacher:
-        sign = 1.0 - 2.0 * (h >> 31).astype(jnp.float32)
-        return sign * scale
+        # sign = 1 - 2 * (h >> 31), as a select
+        return jnp.where(h < jnp.uint32(1 << 31), scale, -scale)
     h2 = _splitmix32(h ^ jnp.uint32(0xDEADBEEF))
-    u1 = (h.astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -32)
-    u2 = (h2.astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -32)
+    u1 = (_u32_to_f32(h) + 0.5) * jnp.float32(2.0 ** -32)
+    u2 = (_u32_to_f32(h2) + 0.5) * jnp.float32(2.0 ** -32)
     z = jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(2.0 * jnp.pi * u2)
     return z * scale
 
 
 def _bdot(a, b, contract_a: int, contract_b: int):
-    """Batched (leading-dim) contraction on the MXU in f32."""
+    """Batched (leading-dim) contraction on the MXU at full f32 precision
+    (the oracle's f32 matvec, not a one-pass bf16 product)."""
     return jax.lax.dot_general(
         a, b, (((contract_a,), (contract_b,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 
-def _divisor_tile(n: int, budget_elems: int) -> int:
-    """Largest divisor of n with at most budget_elems elements."""
-    t = max(1, min(n, budget_elems))
-    while n % t:
-        t -= 1
-    return t
+def _legal_tile(n: int, cap: int, unit: int) -> int:
+    """Largest multiple of ``unit`` that divides n and is at most cap; the
+    whole dim when n <= cap or no such divisor exists."""
+    if n <= cap:
+        return n
+    t = cap - cap % unit
+    while t >= unit and n % t:
+        t -= unit
+    return t if t >= unit else n
 
 
-def _pick_tiles(n_blocks: int, inner: int, other: int,
-                nb_tile: int | None, inner_tile: int | None):
-    """(nb_tile, inner_tile) fitting one A tile in VMEM_TILE_BYTES.
+def _block_rows(n_blocks: int, nb_tile: int | None) -> int:
+    """Blocks per program: the whole stack when it fits one program, else a
+    multiple of 8 (the stack is padded to a multiple of it)."""
+    nb_tile = _SUBLANE if nb_tile is None else nb_tile
+    if n_blocks <= nb_tile:
+        return n_blocks
+    return max(_SUBLANE, nb_tile - nb_tile % _SUBLANE)
 
-    ``inner`` is the tiled A dimension (rows for forward, cols for adjoint),
-    ``other`` the un-tiled one.  nb_tile batches blocks per program."""
-    budget = VMEM_TILE_BYTES // 4
-    if inner_tile is None:
-        inner_tile = _divisor_tile(inner, max(1, budget // max(other, 1)))
-    assert inner % inner_tile == 0
-    # a requested nb_tile is clamped to the VMEM budget too — callers hand
-    # down HBM-sized knobs, and an oversized A tile fails Mosaic on TPU
-    cap = max(1, budget // max(inner_tile * other, 1))
-    nb_tile = cap if nb_tile is None else max(1, min(nb_tile, cap))
-    return min(nb_tile, n_blocks), inner_tile
+
+def _accumulate(o_ref, part, k):
+    """``o_ref`` = sum of ``part`` over the grid's last axis ``k``; the first
+    step stores, so a one-step axis is bitwise the un-tiled result."""
+    @pl.when(k == 0)
+    def _():
+        o_ref[...] = part
+
+    @pl.when(k > 0)
+    def _():
+        o_ref[...] += part
 
 
 def _pad_blocks(x: jnp.ndarray, nb_tile: int) -> jnp.ndarray:
@@ -110,9 +137,14 @@ def _pad_blocks(x: jnp.ndarray, nb_tile: int) -> jnp.ndarray:
 
 
 def _seed_arr(seed) -> jnp.ndarray:
-    """[seed] as a uint32 SMEM operand; accepts python ints and traced
-    scalars (e.g. the shard-folded seeds of the slice driver)."""
-    return jnp.asarray(seed, jnp.uint32).reshape(1)
+    """[[seed]] as a uint32 SMEM operand; accepts python ints and traced
+    scalars (e.g. the shard-folded seeds of the slice driver).  2-D so that
+    a vmapped (m, 1, 1) stack still spans its last two block dims."""
+    return jnp.asarray(seed, jnp.uint32).reshape(1, 1)
+
+
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
 
 
 # ---------------------------------------------------------------------------
@@ -120,35 +152,39 @@ def _seed_arr(seed) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(seed_ref, x_ref, y_ref, *, nb_tile, s_tile, s_block, c,
+def _fwd_kernel(seed_ref, x_ref, y_ref, *, nb_tile, s_tile, c_tile, s_block,
                 rademacher):
     g = pl.program_id(0)                 # block-chunk index
     i = pl.program_id(1)                 # row-tile index inside s_block
-    b0 = jnp.uint32(g * nb_tile)
-    A = _tile_A(seed_ref[0], b0, jnp.uint32(i * s_tile), jnp.uint32(0),
-                nb_tile, s_tile, c, s_block, rademacher)
-    x = x_ref[...]                       # (nb_tile, c)
-    y_ref[...] = _bdot(A, x, 2, 1)       # (nb_tile, s_tile)
+    k = pl.program_id(2)                 # col-tile index inside c (summed)
+    A = _tile_A(seed_ref[0, 0], jnp.uint32(g * nb_tile),
+                jnp.uint32(i * s_tile), jnp.uint32(k * c_tile), nb_tile,
+                s_tile, c_tile, s_block, rademacher)
+    _accumulate(y_ref, _bdot(A, x_ref[...], 2, 1), k)   # (nb_tile, s_tile)
 
 
 def ota_project_pallas(x: jnp.ndarray, seed, s_block: int,
                        rademacher: bool = True, nb_tile: int | None = None,
-                       s_tile: int | None = None,
                        interpret: bool = True) -> jnp.ndarray:
     """x: (n_blocks, c) float32 -> y: (n_blocks, s_block) float32."""
     n_blocks, c = x.shape
-    nb_tile, s_tile = _pick_tiles(n_blocks, s_block, c, nb_tile, s_tile)
+    nb_tile = _block_rows(n_blocks, nb_tile)
+    s_tile = _legal_tile(s_block, _LANE, _LANE)
+    c_tile = _legal_tile(c, max(_LANE, VMEM_TILE_BYTES // 4
+                                // (nb_tile * s_tile)), _LANE)
     x_p = _pad_blocks(x.astype(jnp.float32), nb_tile)
-    grid = (x_p.shape[0] // nb_tile, s_block // s_tile)
+    grid = (x_p.shape[0] // nb_tile, s_block // s_tile, c // c_tile)
     kern = functools.partial(_fwd_kernel, nb_tile=nb_tile, s_tile=s_tile,
-                             s_block=s_block, c=c, rademacher=rademacher)
+                             c_tile=c_tile, s_block=s_block,
+                             rademacher=rademacher)
     y = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((nb_tile, c), lambda g, i: (g, 0))],
-        out_specs=pl.BlockSpec((nb_tile, s_tile), lambda g, i: (g, i)),
+                  pl.BlockSpec((nb_tile, c_tile), lambda g, i, k: (g, k))],
+        out_specs=pl.BlockSpec((nb_tile, s_tile), lambda g, i, k: (g, i)),
         out_shape=jax.ShapeDtypeStruct((x_p.shape[0], s_block), jnp.float32),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(_seed_arr(seed), x_p)
     return y[:n_blocks]
@@ -159,35 +195,40 @@ def ota_project_pallas(x: jnp.ndarray, seed, s_block: int,
 # ---------------------------------------------------------------------------
 
 
-def _t_kernel(seed_ref, y_ref, o_ref, *, nb_tile, c_tile, s_block,
+def _t_kernel(seed_ref, y_ref, o_ref, *, nb_tile, s_tile, c_tile, s_block,
               rademacher):
     g = pl.program_id(0)
     j = pl.program_id(1)                 # col-tile index inside c
-    b0 = jnp.uint32(g * nb_tile)
-    A = _tile_A(seed_ref[0], b0, jnp.uint32(0), jnp.uint32(j * c_tile),
-                nb_tile, s_block, c_tile, s_block, rademacher)
-    y = y_ref[...]                       # (nb_tile, s_block)
-    o_ref[...] = _bdot(A, y, 1, 1)       # (nb_tile, c_tile)
+    k = pl.program_id(2)                 # row-tile index inside s (summed)
+    A = _tile_A(seed_ref[0, 0], jnp.uint32(g * nb_tile),
+                jnp.uint32(k * s_tile), jnp.uint32(j * c_tile), nb_tile,
+                s_tile, c_tile, s_block, rademacher)
+    y = y_ref[...][:, None, :]            # (nb_tile, 1, s_tile)
+    _accumulate(o_ref, _bdot(y, A, 2, 1)[:, 0, :], k)   # (nb_tile, c_tile)
 
 
 def ota_project_t_pallas(y: jnp.ndarray, seed, c: int,
                          rademacher: bool = True, nb_tile: int | None = None,
-                         c_tile: int | None = None,
                          interpret: bool = True) -> jnp.ndarray:
     """y: (n_blocks, s_block) float32 -> (n_blocks, c) float32."""
     n_blocks, s_block = y.shape
-    nb_tile, c_tile = _pick_tiles(n_blocks, c, s_block, nb_tile, c_tile)
+    nb_tile = _block_rows(n_blocks, nb_tile)
+    c_tile = _legal_tile(c, 4 * _LANE, _LANE)
+    s_tile = _legal_tile(s_block, max(_LANE, VMEM_TILE_BYTES // 4
+                                      // (nb_tile * c_tile)), _LANE)
     y_p = _pad_blocks(y.astype(jnp.float32), nb_tile)
-    grid = (y_p.shape[0] // nb_tile, c // c_tile)
-    kern = functools.partial(_t_kernel, nb_tile=nb_tile, c_tile=c_tile,
-                             s_block=s_block, rademacher=rademacher)
+    grid = (y_p.shape[0] // nb_tile, c // c_tile, s_block // s_tile)
+    kern = functools.partial(_t_kernel, nb_tile=nb_tile, s_tile=s_tile,
+                             c_tile=c_tile, s_block=s_block,
+                             rademacher=rademacher)
     o = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((nb_tile, s_block), lambda g, j: (g, 0))],
-        out_specs=pl.BlockSpec((nb_tile, c_tile), lambda g, j: (g, j)),
+                  pl.BlockSpec((nb_tile, s_tile), lambda g, j, k: (g, k))],
+        out_specs=pl.BlockSpec((nb_tile, c_tile), lambda g, j, k: (g, j)),
         out_shape=jax.ShapeDtypeStruct((y_p.shape[0], c), jnp.float32),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(_seed_arr(seed), y_p)
     return o[:n_blocks]

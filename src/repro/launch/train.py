@@ -47,12 +47,13 @@ def main() -> int:
     from repro.configs import get_config
     from repro.configs.base import OTAConfig, TrainConfig
     from repro.data.synthetic import TokenStream
+    from repro.launch.mesh import auto_mesh
     from repro.train.checkpoint import save_checkpoint
     from repro.train.trainer import make_train_step
 
     dims = [int(x) for x in args.mesh.split("x")]
     names = ("pod", "data", "model")[-len(dims):]
-    mesh = jax.make_mesh(tuple(dims), names)
+    mesh = auto_mesh(dims, names)
     arch = get_config(args.arch)
     if args.reduced:
         arch = arch.reduced()

@@ -1,33 +1,1 @@
 from repro.sharding.specs import param_specs  # noqa: F401
-
-import jax
-
-
-def shard_map(f, mesh, in_specs, out_specs, axis_names, check_vma=False):
-    """Version-portable partial-manual shard_map.
-
-    jax >= 0.5 exposes ``jax.shard_map(..., axis_names=, check_vma=)``;
-    earlier releases only have ``jax.experimental.shard_map.shard_map`` where
-    the manual axes are specified as the complement (``auto=``) and the
-    replication check is ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
-
-
-def constrain(x, mesh, spec):
-    """Version-portable with_sharding_constraint for bare PartitionSpecs.
-
-    Newer jax resolves the mesh from the surrounding shard_map/jit scope;
-    jax <= 0.4.x needs the mesh context manager to interpret a bare spec.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.lax.with_sharding_constraint(x, spec)
-    with mesh:
-        return jax.lax.with_sharding_constraint(x, spec)

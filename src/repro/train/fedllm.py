@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
+import time
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -70,61 +71,85 @@ def _chunk_metrics(metrics: Dict[str, jnp.ndarray], draw) -> Dict[str, Any]:
     return met
 
 
-def stream_round(scheme: Scheme, gchunks: jnp.ndarray, deltas: jnp.ndarray,
+def _chunk(grads: jnp.ndarray, i) -> jnp.ndarray:
+    """Chunk ``i`` of every device, ``(m, chunk_len)``, read in place from
+    the ``(m, n_chunks, chunk_len)`` gradient block (a reshape of the
+    ``(m, d_pad)`` flat gradients — no transposed copy)."""
+    return jax.lax.dynamic_index_in_dim(grads, i, axis=1, keepdims=False)
+
+
+def _set(buf: jnp.ndarray, i, row: jnp.ndarray) -> jnp.ndarray:
+    """``buf[i] = row`` as an in-place ``dynamic_update_slice``."""
+    return jax.lax.dynamic_update_index_in_dim(buf, row, i, axis=0)
+
+
+def stream_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
                  t, key: jnp.ndarray, ctx: MACContext):
     """One federated round streamed chunk-by-chunk, double-buffered.
 
-    ``gchunks``/``deltas``: (n_chunks, m, chunk_len).  Pipeline shape:
-    the prologue encodes chunk 0; each scan iteration decodes the
-    in-flight chunk ``i-1`` while encoding chunk ``i`` (one body, two
-    independent dataflows — XLA overlaps them); the epilogue decodes the
-    last chunk.  Bitwise-equal to :func:`stream_round_ref` (the straight
-    per-chunk ``round_simulated`` loop) because every chunk sees exactly
-    the same ops with the same ``_chunk_key``; only the schedule differs.
+    ``grads``: (m, n_chunks, chunk_len); ``deltas``: (n_chunks, m,
+    chunk_len).  Pipeline shape: the prologue encodes chunk 0; each scan
+    iteration decodes the in-flight chunk ``i-1`` while encoding chunk
+    ``i`` (one body, two independent dataflows — XLA overlaps them); the
+    epilogue decodes the last chunk.  Bitwise-equal to
+    :func:`stream_round_ref` (the straight per-chunk ``round_simulated``
+    loop) because every chunk sees exactly the same ops with the same
+    ``_chunk_key``; only the schedule differs.
+
+    Chunks are read and the EF state and decoded chunks written in place
+    (``dynamic_slice`` / ``dynamic_update_slice`` on the scan carry), so a
+    round holds no copy of the ``(n_chunks, m, chunk_len)`` arrays — at
+    smollm_360m's published widths each is 5.8 GB at m = 4.
 
     Returns ``(ghats, new_deltas, mets)`` stacked over chunks.
     """
-    n_chunks = gchunks.shape[0]
-    y0, nd0, met0, draw0 = encode_round(scheme, gchunks[0], deltas[0], t,
-                                        _chunk_key(key, 0), ctx)
+    n_chunks, chunk_len = grads.shape[1], grads.shape[2]
+    y0, nd0, met0, draw0 = encode_round(scheme, _chunk(grads, 0), deltas[0],
+                                        t, _chunk_key(key, 0), ctx)
     met0 = _chunk_metrics(met0, draw0)
+    ghats0 = jnp.zeros((n_chunks, chunk_len), jnp.float32)
 
-    def body(y_prev, inp):
-        i, g_i, dl_i = inp
-        ghat_prev = scheme.decode(y_prev, t, ctx)      # PS: chunk i-1
-        y_i, nd_i, met_i, draw_i = encode_round(       # devices: chunk i
-            scheme, g_i, dl_i, t, _chunk_key(key, i), ctx)
-        return y_i, (ghat_prev, nd_i, _chunk_metrics(met_i, draw_i))
+    def body(carry, i):
+        y_prev, dls, ghats = carry
+        ghats = _set(ghats, i - 1, scheme.decode(y_prev, t, ctx))  # PS: i-1
+        y_i, nd_i, met_i, draw_i = encode_round(               # devices: i
+            scheme, _chunk(grads, i), dls[i], t, _chunk_key(key, i), ctx)
+        return (y_i, _set(dls, i, nd_i), ghats), _chunk_metrics(met_i,
+                                                                draw_i)
 
-    idx = jnp.arange(1, n_chunks)
-    y_last, (ghats_head, nds_tail, mets_tail) = jax.lax.scan(
-        body, y0, (idx, gchunks[1:], deltas[1:]))
-    ghat_last = scheme.decode(y_last, t, ctx)
-    ghats = jnp.concatenate([ghats_head, ghat_last[None]], axis=0)
-    new_deltas = jnp.concatenate([nd0[None], nds_tail], axis=0)
+    (y_last, new_deltas, ghats), mets_tail = jax.lax.scan(
+        body, (y0, _set(deltas, 0, nd0), ghats0), jnp.arange(1, n_chunks))
+    ghats = _set(ghats, n_chunks - 1, scheme.decode(y_last, t, ctx))
     mets = jax.tree.map(lambda a, b: jnp.concatenate([a[None], b], axis=0),
                         met0, mets_tail)
     return ghats, new_deltas, mets
 
 
-def stream_round_ref(scheme: Scheme, gchunks: jnp.ndarray,
+def _chunk_loop(round_fn, grads: jnp.ndarray, deltas: jnp.ndarray,
+                key: jnp.ndarray):
+    """Chunk ``i`` is ``round_fn(grads_i, deltas_i, _chunk_key(key, i))``,
+    one after another, with the EF state updated in place."""
+    def body(dls, i):
+        ghat, nd, met = round_fn(_chunk(grads, i), dls[i], _chunk_key(key, i))
+        return _set(dls, i, nd), (ghat, met)
+
+    new_deltas, (ghats, mets) = jax.lax.scan(body, deltas,
+                                             jnp.arange(grads.shape[1]))
+    return ghats, new_deltas, mets
+
+
+def stream_round_ref(scheme: Scheme, grads: jnp.ndarray,
                      deltas: jnp.ndarray, t, key: jnp.ndarray,
                      ctx: MACContext):
     """Non-pipelined reference: chunk i is literally ``round_simulated``
     under ``_chunk_key(key, i)``.  The parity pin for :func:`stream_round`
     (tests/test_fedllm.py)."""
-    def body(_, inp):
-        i, g_i, dl_i = inp
-        ghat, nd, met = round_simulated(scheme, g_i, dl_i, t,
-                                        _chunk_key(key, i), ctx)
-        return None, (ghat, nd, met)
-
-    idx = jnp.arange(gchunks.shape[0])
-    _, (ghats, nds, mets) = jax.lax.scan(body, None, (idx, gchunks, deltas))
-    return ghats, nds, mets
+    return _chunk_loop(
+        lambda g, dl, k: round_simulated(scheme, g, dl, t, k, ctx),
+        grads, deltas, key)
 
 
-def stream_round_masked(scheme: Scheme, gchunks: jnp.ndarray,
+def stream_round_masked(scheme: Scheme, grads: jnp.ndarray,
                         deltas: jnp.ndarray, t, key: jnp.ndarray,
                         mask: jnp.ndarray, ctx: MACContext):
     """Masked-cohort variant: chunk i runs ``round_masked`` (participation
@@ -133,16 +158,9 @@ def stream_round_masked(scheme: Scheme, gchunks: jnp.ndarray,
     at the all-ones mask it is pinned bitwise to ``round_simulated`` and
     hence to :func:`stream_round`."""
     from repro.experiments.engine import round_masked
-
-    def body(_, inp):
-        i, g_i, dl_i = inp
-        ghat, nd, met = round_masked(scheme, g_i, dl_i, t,
-                                     _chunk_key(key, i), mask, ctx)
-        return None, (ghat, nd, met)
-
-    idx = jnp.arange(gchunks.shape[0])
-    _, (ghats, nds, mets) = jax.lax.scan(body, None, (idx, gchunks, deltas))
-    return ghats, nds, mets
+    return _chunk_loop(
+        lambda g, dl, k: round_masked(scheme, g, dl, t, k, mask, ctx),
+        grads, deltas, key)
 
 
 @dataclasses.dataclass
@@ -239,14 +257,13 @@ class CompiledFedLLM:
     def _round(self, sch: Scheme, carry, t, key, mask):
         params, opt_state, deltas = carry
         gflat, loss = self._grads(params, key)
-        gchunks = gflat.reshape(self.m, self.n_chunks,
-                                self.chunk_len).transpose(1, 0, 2)
+        grads = gflat.reshape(self.m, self.n_chunks, self.chunk_len)
         if mask is None:
-            ghats, new_deltas, mets = stream_round(sch, gchunks, deltas,
+            ghats, new_deltas, mets = stream_round(sch, grads, deltas,
                                                    t, key, self.ctx)
         else:
             ghats, new_deltas, mets = stream_round_masked(
-                sch, gchunks, deltas, t, key, mask, self.ctx)
+                sch, grads, deltas, t, key, mask, self.ctx)
         ghat = ghats.reshape(self.d_pad)[: self.d]
         params, opt_state = self.opt.apply(params, self.unravel(ghat),
                                            opt_state)
@@ -274,7 +291,8 @@ class CompiledFedLLM:
             overrides: Optional[Dict[str, jnp.ndarray]] = None):
         """One full (jitted) run from the initial carry."""
         seg = jax.jit(lambda ov, k, c, t: self.run_segment(ov, k, None,
-                                                           c, t))
+                                                           c, t),
+                      donate_argnums=(2,))
         carry, outs = seg(overrides or {}, keys, self.carry0(),
                           jnp.int32(0))
         outs["params"] = carry[0]
@@ -303,9 +321,11 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
     carry is explicit).
 
     Returns ``{"losses", "metrics", "served_tokens", "publish_bitwise",
-    "params"}``; ``publish_bitwise`` stays True iff every round's served
-    params were bitwise-equal to that round's decoded globals
-    (``verify_publish``; the acceptance pin).
+    "params", "round_seconds", "d", "n_chunks"}``; ``publish_bitwise``
+    stays True iff every round's served params were bitwise-equal to that
+    round's decoded globals (``verify_publish``; the acceptance pin).
+    ``round_seconds`` is each round's host wall time, training segment
+    through served batch (the first includes compilation).
     """
     from repro.experiments.engine import round_keys
     from repro.launch.mesh import make_local_mesh
@@ -321,7 +341,8 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
     serve = make_serve_step(arch, mesh, serve_batch,
                             prompt_len + decode_steps)
     keys = round_keys(rounds, seed)
-    seg = jax.jit(lambda k, c, t: fed.run_segment({}, k, None, c, t))
+    seg = jax.jit(lambda k, c, t: fed.run_segment({}, k, None, c, t),
+                  donate_argnums=(1,))
     dev_copy = jax.jit(lambda p: jax.tree.map(jnp.copy, p))
 
     carry, t0 = fed.carry0(), 0
@@ -333,8 +354,9 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
                                    jax.tree.leaves(loaded))
 
     prompt = jnp.zeros((serve_batch, prompt_len), jnp.int32)
-    losses, mets, served, publish_ok = [], [], [], True
+    losses, mets, served, publish_ok, secs = [], [], [], True, []
     for t in range(t0, rounds):
+        tic = time.perf_counter()
         carry, outs = seg(keys[t:t + 1], carry, jnp.int32(t))
         losses.append(float(outs["loss"][0]))
         mets.append({k: float(v[0]) for k, v in outs["metrics"].items()})
@@ -359,6 +381,7 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
                 jnp.int32)
         served.append(np.stack(toks, axis=1))
+        secs.append(time.perf_counter() - tic)
 
         if ckpt and checkpoint_every and (t + 1) % checkpoint_every == 0:
             save_checkpoint(ckpt, jax.tree.map(np.asarray, carry),
@@ -366,4 +389,5 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
 
     return {"losses": np.asarray(losses), "metrics": mets,
             "served_tokens": served, "publish_bitwise": publish_ok,
-            "params": carry[0]}
+            "params": carry[0], "round_seconds": secs, "d": fed.d,
+            "n_chunks": fed.n_chunks}

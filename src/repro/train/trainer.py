@@ -39,7 +39,6 @@ from repro.core import distributed
 from repro.core.schemes import MACContext, get_scheme
 from repro.models import model as model_lib
 from repro.optim.optim import make_optimizer
-from repro.sharding import constrain, shard_map
 from repro.sharding.specs import named_sharding_tree, param_specs
 
 
@@ -97,11 +96,19 @@ class TrainStep:
         return self._jit_cache[sig]
 
     def init_state(self, key):
+        """``(params, opt_state, delta)`` created in their shardings, so
+        nothing replicated or (M, d)-sized lands on one device first."""
         opt = make_optimizer(self.train)
-        params = model_lib.init_params(self.arch, key)
-        opt_state = opt.init(params)
-        delta = jnp.zeros(self.delta_shape, jnp.dtype(self.ota.state_dtype))
-        return params, opt_state, delta
+
+        def init(key):
+            params = model_lib.init_params(self.arch, key)
+            delta = jnp.zeros(self.delta_shape,
+                              jnp.dtype(self.ota.state_dtype))
+            return params, opt.init(params), delta
+
+        return jax.jit(init, out_shardings=(
+            self.param_sharding, self.opt_sharding,
+            self.delta_sharding))(key)
 
 
 def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
@@ -156,7 +163,7 @@ def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
                                                     has_aux=True)(params)
         gflat, _ = jax.flatten_util.ravel_pytree(grads)
         gflat = jnp.pad(gflat.astype(jnp.float32), (0, d_pad - d))
-        gflat = constrain(gflat, mesh, inner_spec)
+        gflat = jax.lax.with_sharding_constraint(gflat, inner_spec)
         loss_g = loss
         for ax in ota_axes:
             loss_g = jax.lax.psum(loss_g, ax)
@@ -188,13 +195,13 @@ def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
     rep = lambda t: jax.tree.map(lambda _: P(), t)              # noqa: E731
 
     def builder(batch_tree):
-        phase1 = shard_map(
+        phase1 = jax.shard_map(
             grads_body, mesh=mesh,
             in_specs=(rep(aparams),
                       jax.tree.map(lambda _: batch_spec, batch_tree)),
             out_specs=(P(*ota_axes, None), P()),
             axis_names=manual1, check_vma=False)
-        phase2 = shard_map(
+        phase2 = jax.shard_map(
             agg_body, mesh=mesh,
             in_specs=(delta_spec_full, delta_spec_full, P(), P()),
             out_specs=(P(None, auto_axes if auto_axes else None),
@@ -336,7 +343,8 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
         (loss, metrics), grads = jax.value_and_grad(local_loss,
                                                     has_aux=True)(params)
         grads = jax.tree.map(
-            lambda g, s: constrain(g.astype(jnp.float32), mesh, s),
+            lambda g, s: jax.lax.with_sharding_constraint(
+                g.astype(jnp.float32), s),
             grads, pspecs)
         loss_g = loss
         for ax in ota_axes:
@@ -401,7 +409,7 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
     delta_rep_shape = dims + (d_rep_pad,)
 
     def builder(batch_tree):
-        phase1 = shard_map(
+        phase1 = jax.shard_map(
             grads_body, mesh=mesh,
             in_specs=(rep(aparams),
                       jax.tree.map(lambda _: batch_spec, batch_tree)),
@@ -411,7 +419,7 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
                    *([None] * len(lf.shape)))
                  for _, lf, _, _ in info]), P()),
             axis_names=set(ota_axes), check_vma=False)
-        phase2 = shard_map(
+        phase2 = jax.shard_map(
             agg_body, mesh=mesh,
             in_specs=(grads_specs, delta_sh_spec, delta_rep_spec, P(), P()),
             out_specs=(jax.tree.unflatten(treedef,

@@ -189,11 +189,11 @@ def test_sharded_round_kernel_path_matches_jnp():
     from repro.configs.base import OTAConfig
     from repro.core import distributed
     from repro.core.schemes import MACContext, get_scheme
-    from repro.sharding import shard_map
+    from repro.launch.mesh import auto_mesh
 
     D = 512
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+    mesh = auto_mesh((n_dev,), ("dev",))
     grads = jnp.asarray(
         jax.random.normal(jax.random.PRNGKey(7), (n_dev, D)))
     deltas = jnp.zeros((n_dev, D))
@@ -213,9 +213,10 @@ def test_sharded_round_kernel_path_matches_jnp():
                 jax.random.PRNGKey(3), ctx)
             return ghat
 
-        outs[uk] = shard_map(body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                             out_specs=P(), axis_names={"dev"},
-                             check_vma=False)(grads, deltas)
+        outs[uk] = jax.shard_map(
+            body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+            out_specs=P(), axis_names={"dev"},
+            check_vma=False)(grads, deltas)
     np.testing.assert_allclose(np.asarray(outs[True]),
                                np.asarray(outs[False]),
                                rtol=1e-4, atol=1e-5)

@@ -13,8 +13,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from repro.configs import get_config
 from repro.configs.base import OTAConfig, TrainConfig
+from repro.launch.mesh import auto_mesh
 from repro.train.trainer import make_train_step
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 arch = get_config("smollm_360m").reduced()
 tc = TrainConfig(optimizer="adam", lr=1e-3, warmup_steps=0, total_steps=50,
                  compute_dtype="float32", remat=True)

@@ -264,10 +264,10 @@ def test_imperfect_csi_schemes_on_sharded_drivers(scheme):
     key and indexed per device, so it works at any mesh size."""
     from jax.sharding import PartitionSpec as P
     from repro.core import distributed
-    from repro.sharding import shard_map
+    from repro.launch.mesh import auto_mesh
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+    mesh = auto_mesh((n_dev,), ("dev",))
     grads = jax.random.normal(jax.random.PRNGKey(1), (n_dev, D))
     deltas = jnp.zeros((n_dev, D))
     cfg = _cfg(scheme, projection="blocked", block_size=64, amp_iters=4,
@@ -286,9 +286,10 @@ def test_imperfect_csi_schemes_on_sharded_drivers(scheme):
         return schemes_mod.round_sharded(sch, g, dl, 0,
                                          jax.random.PRNGKey(5), ctx)
 
-    ghat = shard_map(psum_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                     out_specs=P(), axis_names={"dev"},
-                     check_vma=False)(grads, deltas)
+    ghat = jax.shard_map(
+        psum_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P(), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     assert bool(jnp.all(jnp.isfinite(ghat)))
 
     def slice_body(g, dl):
@@ -297,9 +298,10 @@ def test_imperfect_csi_schemes_on_sharded_drivers(scheme):
                                                  jax.random.PRNGKey(5), ctx)
         return ghat_s.reshape(1, -1)
 
-    ghat_s = shard_map(slice_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                       out_specs=P("dev"), axis_names={"dev"},
-                       check_vma=False)(grads, deltas)
+    ghat_s = jax.shard_map(
+        slice_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P("dev"), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     assert bool(jnp.all(jnp.isfinite(ghat_s)))
 
 

@@ -33,9 +33,7 @@ def _fed(chunk_size=1 << 14, m=3, scheme="a_dsgd", use_kernel=False):
 def _chunked_grads(fed, key):
     carry = fed.carry0()
     g, _ = jax.jit(fed._grads)(carry[0], key)
-    gch = g.reshape(fed.m, fed.n_chunks,
-                    fed.chunk_len).transpose(1, 0, 2)
-    return carry, gch
+    return carry, g.reshape(fed.m, fed.n_chunks, fed.chunk_len)
 
 
 def test_two_rounds_smoke():
@@ -98,7 +96,7 @@ def test_kernel_encode_path_on_streamed_chunks():
     fed_k = _fed(use_kernel=True)
     fed_r = _fed(use_kernel=False)
     carry, gch = _chunked_grads(fed_r, key)
-    gch1, dl1 = gch[:1], carry[2][:1]       # one chunk is enough
+    gch1, dl1 = gch[:, :1], carry[2][:1]    # one chunk is enough
     a = jax.jit(lambda: stream_round(fed_k.scheme, gch1, dl1, 0, key,
                                      fed_k.ctx))()
     b = jax.jit(lambda: stream_round(fed_r.scheme, gch1, dl1, 0, key,

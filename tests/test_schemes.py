@@ -119,11 +119,11 @@ def test_csi_err_zero_is_fading_golden():
 
 def test_ideal_simulated_matches_sharded_single_host():
     from jax.sharding import PartitionSpec as P
-    from repro.sharding import shard_map
+    from repro.launch.mesh import auto_mesh
 
     cfg = OTAConfig(scheme="ideal", total_steps=10)
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+    mesh = auto_mesh((n_dev,), ("dev",))
     grads = jnp.asarray(_GOLDEN["grads"][:n_dev])
     deltas = jnp.zeros((n_dev, D))
     sch = get_scheme(cfg, D, n_dev)
@@ -138,9 +138,10 @@ def test_ideal_simulated_matches_sharded_single_host():
                                             jax.random.PRNGKey(3), ctx)
         return ghat
 
-    ghat_sh = shard_map(body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                        out_specs=P(), axis_names={"dev"},
-                        check_vma=False)(grads, deltas)
+    ghat_sh = jax.shard_map(
+        body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P(), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     np.testing.assert_allclose(np.asarray(ghat_sim), np.asarray(ghat_sh),
                                rtol=1e-6, atol=1e-7)
 
@@ -151,10 +152,10 @@ def test_fading_reaches_sharded_drivers():
     accumulates into the error state (truncated inversion, follow-up [34])."""
     from jax.sharding import PartitionSpec as P
     from repro.core import distributed
-    from repro.sharding import shard_map
+    from repro.launch.mesh import auto_mesh
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+    mesh = auto_mesh((n_dev,), ("dev",))
     grads = jnp.asarray(_GOLDEN["grads"][:n_dev])
     deltas = jnp.zeros((n_dev, D))
     cfg = OTAConfig(scheme="a_dsgd_fading", fading_threshold=1e9,
@@ -170,9 +171,10 @@ def test_fading_reaches_sharded_drivers():
                                              jax.random.PRNGKey(5), ctx)
         return nd.reshape(1, -1)
 
-    nd = shard_map(slice_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                   out_specs=P("dev"), axis_names={"dev"},
-                   check_vma=False)(grads, deltas)
+    nd = jax.shard_map(
+        slice_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P("dev"), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     # silent device: Delta' = g + Delta (here Delta = 0)
     np.testing.assert_allclose(np.asarray(nd), np.asarray(grads), rtol=1e-6)
 
@@ -181,9 +183,10 @@ def test_fading_reaches_sharded_drivers():
                                          0, jax.random.PRNGKey(5), ctx)
         return nd.reshape(1, -1)
 
-    nd2 = shard_map(psum_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                    out_specs=P("dev"), axis_names={"dev"},
-                    check_vma=False)(grads, deltas)
+    nd2 = jax.shard_map(
+        psum_body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P("dev"), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     np.testing.assert_allclose(np.asarray(nd2), np.asarray(grads), rtol=1e-6)
 
 
@@ -191,11 +194,11 @@ def test_ideal_slice_driver_matches_mean():
     """The generic slice driver (distributed.sharded_round) on one host."""
     from jax.sharding import PartitionSpec as P
     from repro.core import distributed
-    from repro.sharding import shard_map
+    from repro.launch.mesh import auto_mesh
 
     cfg = OTAConfig(scheme="ideal", total_steps=10)
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("dev",))
+    mesh = auto_mesh((n_dev,), ("dev",))
     grads = jnp.asarray(_GOLDEN["grads"][:n_dev])
     deltas = jnp.zeros((n_dev, D))
     sch = get_scheme(cfg, D, n_dev)
@@ -207,8 +210,9 @@ def test_ideal_slice_driver_matches_mean():
                                                 jax.random.PRNGKey(3), ctx)
         return ghat
 
-    ghat = shard_map(body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-                     out_specs=P(), axis_names={"dev"},
-                     check_vma=False)(grads, deltas)
+    ghat = jax.shard_map(
+        body, mesh=mesh, in_specs=(P("dev"), P("dev")),
+        out_specs=P(), axis_names={"dev"},
+        check_vma=False)(grads, deltas)
     np.testing.assert_allclose(np.asarray(ghat),
                                np.asarray(grads.mean(0)), rtol=1e-5)
