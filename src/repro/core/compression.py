@@ -12,11 +12,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.tracing import stage
+
 # ---------------------------------------------------------------------------
 # sparsification
 # ---------------------------------------------------------------------------
 
 
+@stage("threshold")
 def top_k_sparsify(v: jnp.ndarray, k: int) -> jnp.ndarray:
     """Exact sp_k: keep the k largest-magnitude entries of v (paper Alg. 1)."""
     d = v.shape[-1]
@@ -34,6 +37,7 @@ def topk_threshold(v: jnp.ndarray, k: int) -> jnp.ndarray:
     return jax.lax.top_k(jnp.abs(v), min(k, v.shape[-1]))[0][..., -1]
 
 
+@stage("threshold")
 def sampled_topk_threshold(v: jnp.ndarray, k: int, key: jnp.ndarray,
                            n_samples: int = 1 << 16) -> jnp.ndarray:
     """Approximate k-th largest |v| from a strided sample (framework scale).
@@ -81,8 +85,9 @@ def sbc_quantize(v: jnp.ndarray, q_t: jnp.ndarray, q_max: int) -> jnp.ndarray:
     assert v.ndim == 1, "sbc_quantize is per-device; vmap for batches"
     d = v.shape[-1]
     q_max = min(q_max, d)
-    top_vals, _ = jax.lax.top_k(v, q_max)          # descending
-    bot_vals, _ = jax.lax.top_k(-v, q_max)         # descending of -v
+    with stage("threshold"):
+        top_vals, _ = jax.lax.top_k(v, q_max)      # descending
+        bot_vals, _ = jax.lax.top_k(-v, q_max)     # descending of -v
     qi = jnp.clip(jnp.asarray(q_t, jnp.int32) - 1, 0, q_max - 1)
     # dynamic thresholds: q_t-th largest / q_t-th smallest
     hi_thresh = top_vals[qi]

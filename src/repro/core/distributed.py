@@ -32,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core import channel
 from repro.kernels import ref
+from repro.tracing import stage
 
 
 # ---------------------------------------------------------------------------
@@ -126,62 +127,66 @@ def sharded_round(scheme, g_slice: jnp.ndarray, delta_slice: jnp.ndarray,
             g_slice, ctx.device_axes[-1],
             axis_index_groups=[list(g) for g in ctx.groups]) / group_size
 
-    if scheme.analog:
-        # per-device channel draw (same h on every shard of a device-replica:
-        # the full-M realisation is evaluated from the shared round key and
-        # indexed by the device row, never by the shard index)
-        draw = sharded_channel_draw(scheme, key, step, ctx)
-        ctx = ctx.with_p_factor(draw.p_factor)
-    frame, new_delta, metrics = scheme.encode_slice(
-        g_slice, delta_slice, step, key, ctx)
-    if scheme.analog:
-        amp = channel_amp(draw)
-        frame = {k: (v * amp.astype(v.dtype) if v is not None else None)
-                 for k, v in frame.items()}
-        new_delta = jnp.where(draw.active, new_delta,
-                              scheme.silent_state(g_slice, delta_slice,
-                                                  new_delta))
+    with stage("encode"):
+        if scheme.analog:
+            # per-device channel draw (same h on every shard of a
+            # device-replica: the full-M realisation is evaluated from the
+            # shared round key and indexed by the device row, never by the
+            # shard index)
+            draw = sharded_channel_draw(scheme, key, step, ctx)
+            ctx = ctx.with_p_factor(draw.p_factor)
+        frame, new_delta, metrics = scheme.encode_slice(
+            g_slice, delta_slice, step, key, ctx)
+        if scheme.analog:
+            amp = channel_amp(draw)
+            frame = {k: (v * amp.astype(v.dtype) if v is not None else None)
+                     for k, v in frame.items()}
+            new_delta = jnp.where(draw.active, new_delta,
+                                  scheme.silent_state(g_slice, delta_slice,
+                                                      new_delta))
 
-    # --- the MAC: superposition over device axes + AWGN ---------------------
-    body = frame["body"]
-    if ctx.frame_dtype is not None and scheme.analog:
-        # the narrow-psum optimisation only applies to analog frames, whose
-        # quantisation noise hides under the channel AWGN; non-analog
-        # aggregation (ideal benchmark, digital) stays exact in f32
-        body = body.astype(ctx.frame_dtype)
-    y_body = psum_all(body, ctx.device_axes).astype(jnp.float32)
-    slots = frame.get("slots")
-    y_slots = (psum_all(slots, ctx.device_axes)
-               if slots is not None else None)
-    if group_size > 1:
-        y_body = y_body / group_size
-        if y_slots is not None:
-            y_slots = y_slots / group_size
-    if scheme.analog:
-        sigma2 = round_sigma2(scheme, draw)
-        shard_idx, n_shards = shard_info(ctx.shard_axes)
-        body_key = jax.random.fold_in(key, shard_idx.astype(jnp.int32))
-        n_sites = (len(ctx.groups)
-                   if ctx.site_mac and ctx.groups is not None else 1)
-        if n_sites > 1:
-            # hierarchical MAC: each edge-site group's partial sum carries
-            # its own receiver AWGN per channel slice (summed by the PS
-            # combine), mirroring round_sharded's site path
-            y_body = y_body + channel.site_awgn(
-                body_key, y_body.shape, sigma2, n_sites,
-                site_noise_scale=ctx.site_noise_scale)
+        # --- the MAC: superposition over device axes + AWGN -----------------
+        body = frame["body"]
+        if ctx.frame_dtype is not None and scheme.analog:
+            # the narrow-psum optimisation only applies to analog frames,
+            # whose quantisation noise hides under the channel AWGN;
+            # non-analog aggregation (ideal benchmark, digital) stays exact
+            # in f32
+            body = body.astype(ctx.frame_dtype)
+        y_body = psum_all(body, ctx.device_axes).astype(jnp.float32)
+        slots = frame.get("slots")
+        y_slots = (psum_all(slots, ctx.device_axes)
+                   if slots is not None else None)
+        if group_size > 1:
+            y_body = y_body / group_size
             if y_slots is not None:
-                slot_key = jax.random.fold_in(key, n_shards + 7)
-                y_slots = y_slots + channel.site_awgn(
-                    slot_key, y_slots.shape, sigma2, n_sites,
+                y_slots = y_slots / group_size
+        if scheme.analog:
+            sigma2 = round_sigma2(scheme, draw)
+            shard_idx, n_shards = shard_info(ctx.shard_axes)
+            body_key = jax.random.fold_in(key, shard_idx.astype(jnp.int32))
+            n_sites = (len(ctx.groups)
+                       if ctx.site_mac and ctx.groups is not None else 1)
+            if n_sites > 1:
+                # hierarchical MAC: each edge-site group's partial sum
+                # carries its own receiver AWGN per channel slice (summed by
+                # the PS combine), mirroring round_sharded's site path
+                y_body = y_body + channel.site_awgn(
+                    body_key, y_body.shape, sigma2, n_sites,
                     site_noise_scale=ctx.site_noise_scale)
-        else:
-            y_body = y_body + channel.awgn(body_key, y_body.shape, sigma2)
-            if y_slots is not None:
-                slot_key = jax.random.fold_in(key, n_shards + 7)
-                y_slots = y_slots + channel.awgn(slot_key, y_slots.shape,
-                                                 sigma2)
+                if y_slots is not None:
+                    slot_key = jax.random.fold_in(key, n_shards + 7)
+                    y_slots = y_slots + channel.site_awgn(
+                        slot_key, y_slots.shape, sigma2, n_sites,
+                        site_noise_scale=ctx.site_noise_scale)
+            else:
+                y_body = y_body + channel.awgn(body_key, y_body.shape, sigma2)
+                if y_slots is not None:
+                    slot_key = jax.random.fold_in(key, n_shards + 7)
+                    y_slots = y_slots + channel.awgn(slot_key, y_slots.shape,
+                                                     sigma2)
 
-    ghat_slice = scheme.decode_slice({"body": y_body, "slots": y_slots},
-                                     step, ctx)
+    with stage("decode"):
+        ghat_slice = scheme.decode_slice({"body": y_body, "slots": y_slots},
+                                         step, ctx)
     return ghat_slice, new_delta, metrics

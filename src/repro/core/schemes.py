@@ -67,6 +67,7 @@ from repro.core.amp import amp_decode
 from repro.core.projection import DenseProjector, make_projector
 from repro.kernels import ops, ref
 from repro.robust import faults
+from repro.tracing import stage
 
 
 # ---------------------------------------------------------------------------
@@ -560,13 +561,14 @@ class ADSGDScheme(Scheme):
         k = max(1, int(cfg.k_frac * cfg.s_frac * d_pad))
         stride = max(1, d_local // ctx.sample_per_shard)
         n_s = d_local // stride
-        local_sample = jnp.abs(jax.lax.slice_in_dim(g_ec, 0, n_s * stride,
-                                                    stride, axis=0))
-        all_samples = (jax.lax.all_gather(local_sample,
-                                          ctx.shard_axes).reshape(-1)
-                       if ctx.shard_axes else local_sample)
-        q = 1.0 - k / d_pad
-        tau = jnp.quantile(all_samples, q)
+        with stage("threshold"):
+            local_sample = jnp.abs(jax.lax.slice_in_dim(
+                g_ec, 0, n_s * stride, stride, axis=0))
+            all_samples = (jax.lax.all_gather(local_sample,
+                                              ctx.shard_axes).reshape(-1)
+                           if ctx.shard_axes else local_sample)
+            q = 1.0 - k / d_pad
+            tau = jnp.quantile(all_samples, q)
         keep = jnp.abs(g_ec) >= tau
         g_sp = jnp.where(keep, g_ec, 0.0)
         new_state = (g_ec - g_sp).astype(state_slice.dtype)
@@ -853,6 +855,7 @@ def round_sigma2(scheme: Scheme, draw: ChannelDraw):
     return scheme.cfg.sigma2 * draw.noise_scale
 
 
+@stage("encode")
 def encode_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
                  step, key: jnp.ndarray, ctx: MACContext):
     """The device/channel half of :func:`round_simulated`: per-device
@@ -884,6 +887,14 @@ def encode_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
     return y, new_deltas, metrics, draw
 
 
+@stage("decode")
+def decode_round(scheme: Scheme, y: jnp.ndarray, step,
+                 ctx: Optional[MACContext]) -> jnp.ndarray:
+    """The PS half of a round: ``scheme.decode`` of the MAC output, under
+    the ``decode`` stage scope; every round function decodes through here."""
+    return scheme.decode(y, step, ctx)
+
+
 def round_simulated(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
                     step, key: jnp.ndarray,
                     ctx: Optional[MACContext] = None):
@@ -895,7 +906,7 @@ def round_simulated(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
                          csi=scheme.csi)
     y, new_deltas, metrics, draw = encode_round(scheme, grads, deltas,
                                                 step, key, ctx)
-    ghat = scheme.decode(y, step, ctx)
+    ghat = decode_round(scheme, y, step, ctx)
     metrics = {k: jnp.mean(v) for k, v in metrics.items()}
     metrics["active_frac"] = jnp.mean(draw.active.astype(jnp.float32))
     if draw.gain is not None:
@@ -947,32 +958,34 @@ def round_sharded(scheme: Scheme, g_local: jnp.ndarray,
     # distinct salts for the three RNG consumers (matching round_simulated):
     # fold 1 -> device-side encode randomness, fold 2 -> the channel draw,
     # fold 0 -> the channel AWGN
-    if scheme.analog:
-        draw = sharded_channel_draw(scheme, key, step, ctx)
-        ctx = ctx.with_p_factor(draw.p_factor)
-    frame, new_delta, metrics = scheme.encode(
-        g_local, delta_local, step, jax.random.fold_in(key, 1), ctx)
-    if scheme.analog:
-        frame = frame * channel_amp(draw, frame.dtype)
-        new_delta = jnp.where(draw.active, new_delta,
-                              scheme.silent_state(g_local, delta_local,
-                                                  new_delta))
-    y = frame
-    for ax in ctx.device_axes:
-        y = jax.lax.psum(y, ax)
-    if group_size > 1:
-        y = y / group_size
-    if scheme.analog:
-        mac_key = jax.random.fold_in(key, 0)
-        sigma2 = round_sigma2(scheme, draw)
-        if ctx.site_mac and ctx.groups is not None and len(ctx.groups) > 1:
-            # hierarchical MAC: every edge-site group's partial OTA sum
-            # carries its own receiver AWGN, summed by the backhaul combine
-            y = y + channel.site_awgn(mac_key, y.shape, sigma2,
-                                      len(ctx.groups),
-                                      site_noise_scale=ctx.site_noise_scale,
-                                      dtype=y.dtype)
-        else:
-            y = y + channel.awgn(mac_key, y.shape, sigma2, y.dtype)
-    ghat = scheme.decode(y, step, ctx)
+    with stage("encode"):
+        if scheme.analog:
+            draw = sharded_channel_draw(scheme, key, step, ctx)
+            ctx = ctx.with_p_factor(draw.p_factor)
+        frame, new_delta, metrics = scheme.encode(
+            g_local, delta_local, step, jax.random.fold_in(key, 1), ctx)
+        if scheme.analog:
+            frame = frame * channel_amp(draw, frame.dtype)
+            new_delta = jnp.where(draw.active, new_delta,
+                                  scheme.silent_state(g_local, delta_local,
+                                                      new_delta))
+        y = frame
+        for ax in ctx.device_axes:
+            y = jax.lax.psum(y, ax)
+        if group_size > 1:
+            y = y / group_size
+        if scheme.analog:
+            mac_key = jax.random.fold_in(key, 0)
+            sigma2 = round_sigma2(scheme, draw)
+            if (ctx.site_mac and ctx.groups is not None
+                    and len(ctx.groups) > 1):
+                # hierarchical MAC: every edge-site group's partial OTA sum
+                # carries its own receiver AWGN, summed by the backhaul
+                # combine
+                y = y + channel.site_awgn(
+                    mac_key, y.shape, sigma2, len(ctx.groups),
+                    site_noise_scale=ctx.site_noise_scale, dtype=y.dtype)
+            else:
+                y = y + channel.awgn(mac_key, y.shape, sigma2, y.dtype)
+    ghat = decode_round(scheme, y, step, ctx)
     return ghat, new_delta, metrics

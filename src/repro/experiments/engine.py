@@ -40,6 +40,7 @@ from repro.local.work import (
 )
 from repro.optim.optim import Optimizer
 from repro.robust import aggregators, faults, guards
+from repro.tracing import span, stage
 from repro.train.paper_repro import (
     accuracy, ce_loss, device_grads, flat_grad_fn, init_linear,
 )
@@ -162,71 +163,72 @@ def round_masked(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
         grads = faults.apply_gradient_faults(
             grads, fault, byz_attack=cfg.byz_attack,
             byz_scale=scheme.byz_scale)
-    active = draw.active
-    frames, new_deltas, metrics = jax.vmap(
-        lambda g, dl, kk, pf: scheme.encode(g, dl, step, kk,
-                                            ctx.with_p_factor(pf)))(
-            grads, deltas, dev_keys, draw.p_factor)
-    if scheme.analog:
-        if robust:
-            # make_frame normalises every frame to P_t, so an analog
-            # attacker's leverage is transmit *power*, not gradient scale:
-            # Byzantine frames violate the power constraint by byz_scale
-            # in amplitude, and dropouts leave the transmit set mid-round
-            byz_amp = jnp.where(fault.byz, scheme.byz_scale, 1.0)
-            frames = frames * byz_amp[:, None].astype(frames.dtype)
-            active = active & ~fault.dropout
-        if cfg.clip_power:
-            # transmit-side hardware cap: the analog defence (bounds the
-            # power any device — honest or Byzantine — can put on the MAC)
-            frames = aggregators.clip_frame_power(
-                frames, scheme.power_cap * scheme.p_t(step))
-        if robust:
-            # after the clip: a power limiter cannot repair a broken DAC
-            frames = faults.apply_frame_faults(frames, fault)
-        new_deltas = jnp.where(active[:, None], new_deltas,
-                               scheme.silent_state(true_grads, deltas,
-                                                   new_deltas))
-        active = active & mask_b
-        frames = schemes_mod.apply_channel_gain(
-            frames, draw._replace(active=active))
-        mac_key = jax.random.fold_in(key, 0)
-        sigma2 = schemes_mod.round_sigma2(scheme, draw)
-        y = (channel.mac_sum(frames, mac_key, sigma2) if mac is None
-             else mac(frames, mac_key, sigma2))
-    else:
-        if robust:
-            # dropouts know they failed -> bank their whole update; erased
-            # packets are lost in the channel and poisoned packets carry
-            # garbage payloads — either way the unaware device's state
-            # evolves as if sent
-            frames = faults.apply_frame_faults(frames, fault)
-            new_deltas = jnp.where(
-                fault.dropout[:, None],
-                scheme.silent_state(true_grads, deltas, new_deltas),
-                new_deltas)
-            active = active & ~fault.dropout & ~fault.erased
-        if sched is not None:
-            # an unscheduled digital device knows it was not granted a
-            # subband this round and banks its whole update (EF over the
-            # digital link, like a robust dropout that saw it coming)
-            new_deltas = jnp.where(
-                sched[:, None], new_deltas,
-                scheme.silent_state(true_grads, deltas, new_deltas))
-        active = active & mask_b
-        if cfg.aggregator != "mean":
-            y = aggregators.robust_combine(
-                frames, active, m_eff, aggregator=cfg.aggregator,
-                trim_frac=scheme.trim_frac, norm_cap=scheme.norm_cap)
+    with stage("encode"):
+        active = draw.active
+        frames, new_deltas, metrics = jax.vmap(
+            lambda g, dl, kk, pf: scheme.encode(g, dl, step, kk,
+                                                ctx.with_p_factor(pf)))(
+                grads, deltas, dev_keys, draw.p_factor)
+        if scheme.analog:
+            if robust:
+                # make_frame normalises every frame to P_t, so an analog
+                # attacker's leverage is transmit *power*, not gradient scale:
+                # Byzantine frames violate the power constraint by byz_scale
+                # in amplitude, and dropouts leave the transmit set mid-round
+                byz_amp = jnp.where(fault.byz, scheme.byz_scale, 1.0)
+                frames = frames * byz_amp[:, None].astype(frames.dtype)
+                active = active & ~fault.dropout
+            if cfg.clip_power:
+                # transmit-side hardware cap: the analog defence (bounds the
+                # power any device — honest or Byzantine — can put on the MAC)
+                frames = aggregators.clip_frame_power(
+                    frames, scheme.power_cap * scheme.p_t(step))
+            if robust:
+                # after the clip: a power limiter cannot repair a broken DAC
+                frames = faults.apply_frame_faults(frames, fault)
+            new_deltas = jnp.where(active[:, None], new_deltas,
+                                   scheme.silent_state(true_grads, deltas,
+                                                       new_deltas))
+            active = active & mask_b
+            frames = schemes_mod.apply_channel_gain(
+                frames, draw._replace(active=active))
+            mac_key = jax.random.fold_in(key, 0)
+            sigma2 = schemes_mod.round_sigma2(scheme, draw)
+            y = (channel.mac_sum(frames, mac_key, sigma2) if mac is None
+                 else mac(frames, mac_key, sigma2))
         else:
-            # the literal sum (never the trimmed path at trim=0: a sorted
-            # sum re-associates, which is not bitwise the same reduction)
-            frames = frames * (active if (robust or sched is not None)
-                               else mask_b)[:, None]
-            y = jnp.sum(frames, axis=0)
+            if robust:
+                # dropouts know they failed -> bank their whole update; erased
+                # packets are lost in the channel and poisoned packets carry
+                # garbage payloads — either way the unaware device's state
+                # evolves as if sent
+                frames = faults.apply_frame_faults(frames, fault)
+                new_deltas = jnp.where(
+                    fault.dropout[:, None],
+                    scheme.silent_state(true_grads, deltas, new_deltas),
+                    new_deltas)
+                active = active & ~fault.dropout & ~fault.erased
+            if sched is not None:
+                # an unscheduled digital device knows it was not granted a
+                # subband this round and banks its whole update (EF over the
+                # digital link, like a robust dropout that saw it coming)
+                new_deltas = jnp.where(
+                    sched[:, None], new_deltas,
+                    scheme.silent_state(true_grads, deltas, new_deltas))
+            active = active & mask_b
+            if cfg.aggregator != "mean":
+                y = aggregators.robust_combine(
+                    frames, active, m_eff, aggregator=cfg.aggregator,
+                    trim_frac=scheme.trim_frac, norm_cap=scheme.norm_cap)
+            else:
+                # the literal sum (never the trimmed path at trim=0: a sorted
+                # sum re-associates, which is not bitwise the same reduction)
+                frames = frames * (active if (robust or sched is not None)
+                                   else mask_b)[:, None]
+                y = jnp.sum(frames, axis=0)
     # padded devices do not exist: their error state must not evolve
     new_deltas = jnp.where(mask_b[:, None], new_deltas, deltas)
-    ghat = scheme.decode(y, step, ctx)
+    ghat = schemes_mod.decode_round(scheme, y, step, ctx)
     w = mask.astype(jnp.float32)
     metrics = {k: jnp.sum(v * w) / m_eff for k, v in metrics.items()}
     metrics["active_frac"] = jnp.sum(active.astype(jnp.float32)) / m_eff
@@ -312,14 +314,16 @@ class CompiledExperiment:
                       + ((sstate,) if self._sched_state else ()))
         if lw.identity:
             # the pre-axis jaxpr, byte-for-byte — pins the goldens
-            grads, momenta = device_grads(
-                params, self.unravel, self.xd, self.yd, momenta,
-                local_steps=exp.local_steps, local_lr=exp.local_lr,
-                momentum_correction=exp.momentum_correction)
+            with stage("grads"):
+                grads, momenta = device_grads(
+                    params, self.unravel, self.xd, self.yd, momenta,
+                    local_steps=exp.local_steps, local_lr=exp.local_lr,
+                    momentum_correction=exp.momentum_correction)
         else:
-            grads, momenta, new_duals = local_device_grads(
-                lw, self._grad_fn, params, self.xd, self.yd, momenta,
-                duals, momentum_correction=exp.momentum_correction)
+            with stage("grads"):
+                grads, momenta, new_duals = local_device_grads(
+                    lw, self._grad_fn, params, self.xd, self.yd, momenta,
+                    duals, momentum_correction=exp.momentum_correction)
             if lw.has_dual:
                 # padded phantom devices do not exist: their dual must not
                 # evolve (same keep-rule round_masked applies to deltas)
@@ -362,19 +366,22 @@ class CompiledExperiment:
         extras = ((deltas, momenta) + ((duals,) if lw.has_dual else ())
                   + ((sstate,) if self._sched_state else ()))
         if exp.guard is None:
-            params, opt_state = self.opt.apply(params, self.unravel(ghat),
-                                               opt_state)
-            out = {"acc": accuracy(params, self.xt, self.yt),
-                   "loss": ce_loss(params, self.xt, self.yt),
-                   "metrics": met}
+            with stage("optimizer"):
+                params, opt_state = self.opt.apply(
+                    params, self.unravel(ghat), opt_state)
+            with stage("eval"):
+                out = {"acc": accuracy(params, self.xt, self.yt),
+                       "loss": ce_loss(params, self.xt, self.yt),
+                       "metrics": met}
             return (params, opt_state) + extras, out
         (params, opt_state, extras, gstate, loss,
          gmet) = guards.guarded_step(
             exp.guard, gstate, self.opt, params, opt_state, ghat,
             self.unravel, extras=extras, old_extras=old_extras,
             loss_fn=lambda p: ce_loss(p, self.xt, self.yt))
-        out = {"acc": accuracy(params, self.xt, self.yt), "loss": loss,
-               "metrics": {**met, **gmet}}
+        with stage("eval"):
+            out = {"acc": accuracy(params, self.xt, self.yt), "loss": loss,
+                   "metrics": {**met, **gmet}}
         return (params, opt_state) + tuple(extras) + (gstate,), out
 
     def _scan(self, overrides, keys, mask):
@@ -464,6 +471,10 @@ def run_checkpointed(ce, overrides, keys, *, checkpoint_dir: str,
     ``None`` after the first segment boundary at or past it (the snapshot
     is on disk; rerun with ``resume=True`` to finish).  Returns the outs
     dict (with final ``params``) when the run completes.
+
+    Under ``jax.profiler.trace`` each segment shows the host spans
+    ``repro:segment`` (the compiled segment and its outputs read back) and
+    ``repro:checkpoint`` (the snapshot written).
     """
     steps = keys.shape[0]
     every = max(int(checkpoint_every), 1)
@@ -482,12 +493,14 @@ def run_checkpointed(ce, overrides, keys, *, checkpoint_dir: str,
     seg_fn = jax.jit(lambda ov, k, c, t: ce.run_segment(ov, k, mask, c, t))
     while t0 < steps:
         n = min(every, steps - t0)
-        carry, outs = seg_fn(overrides, keys[t0:t0 + n], carry,
-                             jnp.int32(t0))
-        chunks.append(jax.tree.map(np.asarray, outs))
+        with span("segment"):
+            carry, outs = seg_fn(overrides, keys[t0:t0 + n], carry,
+                                 jnp.int32(t0))
+            chunks.append(jax.tree.map(np.asarray, outs))
         t0 += n
-        save_checkpoint(path, {"carry": carry,
-                               "outs": _concat_outs(chunks)}, step=t0)
+        with span("checkpoint"):
+            save_checkpoint(path, {"carry": carry,
+                                   "outs": _concat_outs(chunks)}, step=t0)
         if (stop_after_step is not None and t0 >= stop_after_step
                 and t0 < steps):
             return None

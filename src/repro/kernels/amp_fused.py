@@ -133,5 +133,6 @@ def amp_decode_fused_pallas(yb: jnp.ndarray, seed, c: int, *,
             dimension_semantics=("parallel",),
             vmem_limit_bytes=nb_tile * a_block + _AMP_HEADROOM),
         interpret=interpret,
+        name="amp_decode_fused",
     )(scal, y_p)
     return xb[:n_blocks, 0]

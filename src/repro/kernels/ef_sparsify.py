@@ -67,5 +67,6 @@ def ef_sparsify_pallas(g: jnp.ndarray, delta: jnp.ndarray, tau: jnp.ndarray,
         out_specs=(spec, spec),
         out_shape=out_shape,
         interpret=interpret,
+        name="ef_sparsify",
     )(tau_arr, g_p, d_p)
     return sp.reshape(-1)[:n], nd.reshape(-1)[:n]

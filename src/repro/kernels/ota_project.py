@@ -186,6 +186,7 @@ def ota_project_pallas(x: jnp.ndarray, seed, s_block: int,
         out_shape=jax.ShapeDtypeStruct((x_p.shape[0], s_block), jnp.float32),
         compiler_params=_PARAMS,
         interpret=interpret,
+        name="ota_project",
     )(_seed_arr(seed), x_p)
     return y[:n_blocks]
 
@@ -230,5 +231,6 @@ def ota_project_t_pallas(y: jnp.ndarray, seed, c: int,
         out_shape=jax.ShapeDtypeStruct((y_p.shape[0], c), jnp.float32),
         compiler_params=_PARAMS,
         interpret=interpret,
+        name="ota_project_t",
     )(_seed_arr(seed), y_p)
     return o[:n_blocks]

@@ -39,6 +39,8 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.tracing import stage
+
 
 @dataclass(frozen=True)
 class GuardConfig:
@@ -94,7 +96,8 @@ def guarded_step(guard: GuardConfig, gstate: GuardState, opt, params,
     # a non-finite update would corrupt Adam's moments even on a skipped
     # round — apply the optimizer to a zeroed stand-in and discard it
     ghat_safe = jnp.where(finite, ghat, 0.0)
-    p1, o1 = opt.apply(params, unravel(ghat_safe), opt_state)
+    with stage("optimizer"):
+        p1, o1 = opt.apply(params, unravel(ghat_safe), opt_state)
     # LR backoff by step blending (Adam is scale-invariant in the gradient)
     p1 = jax.tree.map(lambda p0, p: p0 + gstate.lr_scale * (p - p0),
                       params, p1)

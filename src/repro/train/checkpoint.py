@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import ml_dtypes  # noqa: F401  (registers bfloat16 etc. with numpy)
 import numpy as np
 
+from repro.tracing import span
+
 _EMPTY_DICT = "__empty_dict__"
 _EMPTY_TUPLE = "__empty_tuple__"
 _UINT_FOR_WIDTH = {1: np.uint8, 2: np.uint16, 4: np.uint32, 8: np.uint64}
@@ -95,11 +97,15 @@ def _unflatten(flat: Dict[str, np.ndarray]) -> Any:
 
 
 def save_checkpoint(path: str, state: Any, step: int = 0) -> None:
-    flat = _flatten({"state": state, "meta": {"step": np.asarray(int(step))}})
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    tmp = path + ".tmp.npz"
-    np.savez(tmp, **flat)
-    os.replace(tmp, path)
+    """Write ``state`` and ``step`` to ``path`` (host span
+    ``repro:save_checkpoint``)."""
+    with span("save_checkpoint"):
+        flat = _flatten({"state": state,
+                         "meta": {"step": np.asarray(int(step))}})
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        tmp = path + ".tmp.npz"
+        np.savez(tmp, **flat)
+        os.replace(tmp, path)
 
 
 def load_checkpoint(path: str, shardings=None):
@@ -115,17 +121,18 @@ def load_checkpoint(path: str, shardings=None):
     resumed serve/train loop never round-trips a replicated copy through
     the default device (the fedllm mid-sweep resume path).  Its structure
     must match the *restored* tree (post npz round-trip, so tuples where
-    NamedTuples were).
+    NamedTuples were).  Host span ``repro:load_checkpoint``.
     """
-    with np.load(path) as f:
-        flat = {k: f[k] for k in f.files}
-    step = int(flat.pop("meta/step")) if "meta/step" in flat else 0
-    tree = _unflatten(flat)
-    if isinstance(tree, dict):
-        tree.pop("meta", None)
-        tree = tree.get("state", tree)
-    if shardings is not None:
-        import jax
-        tree = jax.tree.map(lambda x, s: jax.device_put(x, s), tree,
-                            shardings)
-    return tree, step
+    with span("load_checkpoint"):
+        with np.load(path) as f:
+            flat = {k: f[k] for k in f.files}
+        step = int(flat.pop("meta/step")) if "meta/step" in flat else 0
+        tree = _unflatten(flat)
+        if isinstance(tree, dict):
+            tree.pop("meta", None)
+            tree = tree.get("state", tree)
+        if shardings is not None:
+            import jax
+            tree = jax.tree.map(lambda x, s: jax.device_put(x, s), tree,
+                                shardings)
+        return tree, step

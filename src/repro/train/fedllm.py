@@ -43,10 +43,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, OTAConfig, TrainConfig
-from repro.core.schemes import (MACContext, Scheme, encode_round,
-                                get_scheme, round_simulated)
+from repro.core.schemes import (MACContext, Scheme, decode_round,
+                                encode_round, get_scheme, round_simulated)
 from repro.models import model as model_lib
 from repro.optim.optim import make_optimizer
+from repro.tracing import span, stage
 from repro.train.trainer import _pad_multiple, abstract_params, ravel_meta
 
 # RNG salts (extending the 0-7 layout in docs/ARCHITECTURE.md): chunk
@@ -83,6 +84,7 @@ def _set(buf: jnp.ndarray, i, row: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.dynamic_update_index_in_dim(buf, row, i, axis=0)
 
 
+@stage("stream")
 def stream_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
                  t, key: jnp.ndarray, ctx: MACContext):
     """One federated round streamed chunk-by-chunk, double-buffered.
@@ -111,7 +113,8 @@ def stream_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
 
     def body(carry, i):
         y_prev, dls, ghats = carry
-        ghats = _set(ghats, i - 1, scheme.decode(y_prev, t, ctx))  # PS: i-1
+        ghats = _set(ghats, i - 1,                             # PS: i-1
+                     decode_round(scheme, y_prev, t, ctx))
         y_i, nd_i, met_i, draw_i = encode_round(               # devices: i
             scheme, _chunk(grads, i), dls[i], t, _chunk_key(key, i), ctx)
         return (y_i, _set(dls, i, nd_i), ghats), _chunk_metrics(met_i,
@@ -119,12 +122,13 @@ def stream_round(scheme: Scheme, grads: jnp.ndarray, deltas: jnp.ndarray,
 
     (y_last, new_deltas, ghats), mets_tail = jax.lax.scan(
         body, (y0, _set(deltas, 0, nd0), ghats0), jnp.arange(1, n_chunks))
-    ghats = _set(ghats, n_chunks - 1, scheme.decode(y_last, t, ctx))
+    ghats = _set(ghats, n_chunks - 1, decode_round(scheme, y_last, t, ctx))
     mets = jax.tree.map(lambda a, b: jnp.concatenate([a[None], b], axis=0),
                         met0, mets_tail)
     return ghats, new_deltas, mets
 
 
+@stage("stream")
 def _chunk_loop(round_fn, grads: jnp.ndarray, deltas: jnp.ndarray,
                 key: jnp.ndarray):
     """Chunk ``i`` is ``round_fn(grads_i, deltas_i, _chunk_key(key, i))``,
@@ -229,6 +233,7 @@ class CompiledFedLLM:
                       cfg.encoder.d_model))
         return b
 
+    @stage("grads")
     def _grads(self, params, key: jnp.ndarray):
         """(m, d_pad) per-device flat gradients + mean local loss.
 
@@ -264,9 +269,10 @@ class CompiledFedLLM:
         else:
             ghats, new_deltas, mets = stream_round_masked(
                 sch, grads, deltas, t, key, mask, self.ctx)
-        ghat = ghats.reshape(self.d_pad)[: self.d]
-        params, opt_state = self.opt.apply(params, self.unravel(ghat),
-                                           opt_state)
+        with stage("optimizer"):
+            ghat = ghats.reshape(self.d_pad)[: self.d]
+            params, opt_state = self.opt.apply(params, self.unravel(ghat),
+                                               opt_state)
         out = {"loss": loss,
                "metrics": {k: jnp.mean(v) for k, v in mets.items()}}
         return (params, opt_state, new_deltas), out
@@ -326,6 +332,11 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
     round's decoded globals (``verify_publish``; the acceptance pin).
     ``round_seconds`` is each round's host wall time, training segment
     through served batch (the first includes compilation).
+
+    Under ``jax.profiler.trace`` each round shows the host spans
+    ``repro:round`` (the training segment and its loss read back),
+    ``repro:publish``, ``repro:verify_publish``, ``repro:serve`` (prefill
+    and greedy decode, each token read back) and ``repro:checkpoint``.
     """
     from repro.experiments.engine import round_keys
     from repro.launch.mesh import make_local_mesh
@@ -357,35 +368,41 @@ def serve_while_train(arch: ArchConfig, rounds: int = 2, *,
     losses, mets, served, publish_ok, secs = [], [], [], True, []
     for t in range(t0, rounds):
         tic = time.perf_counter()
-        carry, outs = seg(keys[t:t + 1], carry, jnp.int32(t))
-        losses.append(float(outs["loss"][0]))
-        mets.append({k: float(v[0]) for k, v in outs["metrics"].items()})
+        with span("round"):
+            carry, outs = seg(keys[t:t + 1], carry, jnp.int32(t))
+            losses.append(float(outs["loss"][0]))
+            mets.append({k: float(v[0]) for k, v in outs["metrics"].items()})
 
         # publish round t's decoded globals (device-side copy so the
         # trainer's live carry is not donated away), then serve from them
-        view = serve.publish(dev_copy(carry[0]))
+        with span("publish"):
+            view = serve.publish(dev_copy(carry[0]))
         if verify_publish:
-            same = all(
-                np.array_equal(np.asarray(a), np.asarray(b))
-                for a, b in zip(jax.tree.leaves(view),
-                                jax.tree.leaves(carry[0])))
+            with span("verify_publish"):
+                same = all(
+                    np.array_equal(np.asarray(a), np.asarray(b))
+                    for a, b in zip(jax.tree.leaves(view),
+                                    jax.tree.leaves(carry[0])))
             publish_ok = publish_ok and same
-        logits, cache = serve.prefill_fn(view, serve.init_cache(), prompt)
-        toks = []
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
-            jnp.int32)
-        for i in range(decode_steps):
-            toks.append(np.asarray(tok)[:, 0])
-            logits, cache = serve.decode_fn(view, cache, tok,
-                                            jnp.int32(prompt_len + i))
+        with span("serve"):
+            logits, cache = serve.prefill_fn(view, serve.init_cache(),
+                                             prompt)
+            toks = []
             tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
                 jnp.int32)
+            for i in range(decode_steps):
+                toks.append(np.asarray(tok)[:, 0])
+                logits, cache = serve.decode_fn(view, cache, tok,
+                                                jnp.int32(prompt_len + i))
+                tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
+                    jnp.int32)
         served.append(np.stack(toks, axis=1))
         secs.append(time.perf_counter() - tic)
 
         if ckpt and checkpoint_every and (t + 1) % checkpoint_every == 0:
-            save_checkpoint(ckpt, jax.tree.map(np.asarray, carry),
-                            step=t + 1)
+            with span("checkpoint"):
+                save_checkpoint(ckpt, jax.tree.map(np.asarray, carry),
+                                step=t + 1)
 
     return {"losses": np.asarray(losses), "metrics": mets,
             "served_tokens": served, "publish_bitwise": publish_ok,

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models import model as model_lib
 from repro.sharding.specs import named_sharding_tree, param_specs
+from repro.tracing import stage
 from repro.train.trainer import abstract_params
 
 
@@ -98,6 +99,7 @@ def make_serve_step(arch: ArchConfig, mesh, batch: int, max_len: int,
 
     enc_sh = tok_spec if arch.encoder is not None else None  # batch over data
 
+    @stage("serve")
     def decode(params, cache, token, pos, *args):
         enc_out = args[0] if args else None
         logits, new_cache = model_lib.decode_step(
@@ -105,6 +107,7 @@ def make_serve_step(arch: ArchConfig, mesh, batch: int, max_len: int,
             compute_dtype=compute_dtype, decode_window=decode_window)
         return logits, new_cache
 
+    @stage("serve")
     def prefill(params, cache, tokens, *args):
         # scan one decode step per prompt position: arch-generic (every
         # model family defines decode_step; the batched-forward fast path
@@ -133,8 +136,8 @@ def make_serve_step(arch: ArchConfig, mesh, batch: int, max_len: int,
     prefill_fn = jax.jit(prefill, in_shardings=tuple(pre_sh),
                          out_shardings=(None, cache_sh),
                          donate_argnums=(1,))
-    publish_fn = jax.jit(lambda p: p, out_shardings=param_sh,
-                         donate_argnums=(0,))
+    publish_fn = jax.jit(stage("serve")(lambda p: p),
+                         out_shardings=param_sh, donate_argnums=(0,))
     return ServeStep(arch=arch, mesh=mesh, batch=batch, max_len=max_len,
                      decode_window=decode_window, param_sharding=param_sh,
                      cache_sharding=cache_sh, decode_fn=decode_fn,

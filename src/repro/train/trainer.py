@@ -40,6 +40,7 @@ from repro.core.schemes import MACContext, get_scheme
 from repro.models import model as model_lib
 from repro.optim.optim import make_optimizer
 from repro.sharding.specs import named_sharding_tree, param_specs
+from repro.tracing import stage
 
 
 def _pad_multiple(d: int, m: int) -> int:
@@ -153,6 +154,7 @@ def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
     inner_spec = P(auto_axes) if auto_axes else P()
 
     # ---------------- phase 1: per-device grads ---------------------------
+    @stage("grads")
     def grads_body(params, batch):
         def local_loss(p):
             return model_lib.loss_fn(p, arch, batch,
@@ -219,8 +221,9 @@ def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
             ghat = ghat_s.reshape(d_pad)
             ghat = jax.lax.with_sharding_constraint(
                 ghat, ns(P(auto_axes) if auto_axes else P()))
-            ghat_tree = unravel(ghat[:d])
-            params, opt_state = opt.apply(params, ghat_tree, opt_state)
+            with stage("optimizer"):
+                ghat_tree = unravel(ghat[:d])
+                params, opt_state = opt.apply(params, ghat_tree, opt_state)
             return params, opt_state, new_delta, {**metrics, **agg_metrics}
 
         in_sh = (param_sh, opt_sh, delta_sh,
@@ -334,6 +337,7 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
         shard_decode=ota.shard_decode, use_kernel=ota.use_kernel)
 
     # ---------------- phase 1: per-device grads (tree out) ----------------
+    @stage("grads")
     def grads_body(params, batch):
         def local_loss(p):
             return model_lib.loss_fn(p, arch, batch,
@@ -434,7 +438,8 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
                 gstacked, grads_specs)
             ghat_tree, nd_sh, nd_rep, met2 = phase2(
                 gstacked, delta["sh"], delta["rep"], step, key)
-            params, opt_state = opt.apply(params, ghat_tree, opt_state)
+            with stage("optimizer"):
+                params, opt_state = opt.apply(params, ghat_tree, opt_state)
             return (params, opt_state, {"sh": nd_sh, "rep": nd_rep},
                     {**metrics, **met2})
 

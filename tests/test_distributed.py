@@ -1,11 +1,16 @@
 """Distributed train-step tests — spawned in subprocesses so the main pytest
 process keeps its single CPU device (the 8-device XLA flag must be set
 before jax initialises)."""
+import json
 import os
 import subprocess
 import sys
 
 import pytest
+
+sys.path.insert(0, os.path.abspath(os.path.join(os.path.dirname(__file__),
+                                                "..")))
+from bench.stages import stages_on  # noqa: E402
 
 _COMMON = r"""
 import os
@@ -134,3 +139,25 @@ assert ts.m_devices == 2
 print("OK", float(met["global_loss"]))
 """)
     assert "OK" in out
+
+
+@pytest.mark.parametrize("layout", ["flat", "sliced"])
+def test_sharded_step_lowers_with_its_stages(layout):
+    """The sharded train step carries the round's stage scopes."""
+    out = _run(r"""
+import json, re
+from repro.train.trainer import make_train_step_sliced
+ota = OTAConfig(scheme="a_dsgd", projection="blocked", block_size=512,
+                s_frac=0.25, k_frac=0.5, p_avg=500.0, total_steps=50,
+                amp_iters=2, layout=LAYOUT)
+mk = make_train_step_sliced if LAYOUT == "sliced" else make_train_step
+ts = mk(arch, tc, ota, mesh, ota_axes=("data",), donate=False)
+state = jax.eval_shape(ts.init_state, jax.random.PRNGKey(0))
+low = ts.jitted(batch).lower(*state, batch, jnp.asarray(0),
+                             jax.random.PRNGKey(0))
+print("LOCS", json.dumps(sorted(set(re.findall(
+    r'loc\("([^"]+)"', low.as_text(debug_info=True))))))
+""".replace("LAYOUT", repr(layout)))
+    locs = json.loads(out.split("LOCS", 1)[1])
+    found = set().union(*(stages_on(p) for p in locs))
+    assert found == {"grads", "encode", "threshold", "decode", "optimizer"}

@@ -69,3 +69,35 @@ def test_kernel_compiles_for_v5e(name, one_chip):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _wrappers():
+    from repro.kernels import ops
+    seed = ((), jnp.uint32)
+    return {
+        "ota_project": (
+            lambda x, sd: ops.ota_project(x, seed=sd, s_block=S_BLOCK,
+                                          use_kernel=True),
+            [((N_BLOCKS, C), jnp.float32), seed]),
+        "ota_project_t": (
+            lambda y, sd: ops.ota_project_t(y, seed=sd, c=C, use_kernel=True),
+            [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
+        "amp_decode_fused": (
+            lambda y, sd: ops.amp_decode_fused(y, seed=sd, c=C, iters=2),
+            [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
+        "ef_sparsify": (
+            lambda g, d: ops.ef_sparsify(g, d, 0.5, use_kernel=True),
+            [((CHUNK,), jnp.float32), ((CHUNK,), jnp.float32)]),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_wrappers()))
+def test_kernel_wrapper_names_its_kernel(name, one_chip, monkeypatch):
+    """Each Pallas kernel is named, so a profile shows ``<name>.<n>``."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    fn, specs = _wrappers()[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    text = jax.jit(fn).lower(*args).as_text()
+    assert f'kernel_name = "{name}"' in text
