@@ -134,6 +134,82 @@ def test_amp_decode_dispatches_to_fused_kernel(monkeypatch):
                                rtol=1e-4, atol=1e-5)
 
 
+# register tiles of the fused decode: several trips of every loop, parity
+# with the jnp path for any tiling, and the tile chooser
+# ---------------------------------------------------------------------------
+
+
+# the cell's tiles, and tiles cut small so that a small block takes several
+# trips of every loop: gen rows and columns, adjoint column tiles and row
+# trips, forward row trips, lane chunks and lane reductions
+_SMALL_TILES = {"_GEN_TILE": (16, 128), "_ADJ_TILE": (32, 256),
+                "_FWD_TILE": (16, 256), "_RED_ROWS": 64}
+
+
+@pytest.mark.parametrize("tiles", ["cell", "small"])
+def test_fused_amp_tiled_matches_jnp(tiles, monkeypatch):
+    """5 blocks of c = 1024, s_block = 256 in chunks of 2 (nb_tile > 1, the
+    last chunk padded), seed and id_offset traced."""
+    from repro.kernels import amp_fused
+    if tiles == "small":
+        for k, v in _SMALL_TILES.items():
+            monkeypatch.setattr(amp_fused, k, v)
+    t = amp_fused._amp_tiles(256, 1024)
+    assert 256 // t.fwd_rows > 1 and 256 // t.gen_rows > 1
+    if tiles == "small":
+        assert 1024 // t.adj_cols > 1 and 256 // t.adj_rows > 1
+        assert 256 // t.red_rows > 1
+    d, c, sb, off = 5 * 1024, 1024, 256, 3
+    proj = BlockedProjector(d=d, block_size=c, s_block=sb, seed=13,
+                            rademacher=True)
+    yb = proj.project(_block_sparse_signal(d, c, sb)).reshape(
+        proj.n_blocks, sb)
+    seed = ref.splitmix32(jnp.uint32(13))
+    # the jnp path with the encoder's block ids off + 0 .. off + 4
+    want = amp_blocked_core(yb, seed, c, iters=12, chunk_blocks=2,
+                            id_offset=off)
+
+    @jax.jit
+    def run(yb, seed, off):
+        return amp_fused.amp_decode_fused_pallas(
+            yb, seed, c, iters=12, nb_tile=2, id_offset=off)
+
+    got = run(yb, seed, jnp.uint32(off))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-5)
+    assert float(jnp.max(jnp.abs(want))) > 0.1      # a nontrivial decode
+
+
+@pytest.mark.parametrize("c,sb", [(4096, 1024), (1024, 256), (256, 128),
+                                  (256, 64), (128, 64), (64, 32)])
+def test_amp_tile_chooser(c, sb):
+    """The cell's block and every block the kernel tests decode: each tile
+    divides its dim, obeys the (8, 128) rule, and the estimated live vregs
+    stay within the budget."""
+    from repro.kernels import amp_fused
+    t = amp_fused._amp_tiles(sb, c)
+    assert t.sub == 8
+    for r in (t.gen_rows, t.adj_rows, t.fwd_rows, t.red_rows):
+        assert sb % r == 0 and r % 8 == 0 and r % t.sub == 0
+    for w in (t.gen_cols, t.adj_cols, t.fwd_cols):
+        assert c % w == 0 and (w % 128 == 0 or w == c)
+    assert amp_fused._live_vregs(t) <= amp_fused._LIVE_VREGS
+    if (c, sb) == (4096, 1024):               # the tiles chosen on the v5e
+        assert (t.gen_rows, t.gen_cols) == amp_fused._GEN_TILE
+        assert (t.adj_rows, t.adj_cols) == amp_fused._ADJ_TILE
+        assert (t.fwd_rows, t.fwd_cols) == amp_fused._FWD_TILE
+        assert t.red_rows == amp_fused._RED_ROWS
+
+
+def test_amp_tile_chooser_takes_odd_dims_whole():
+    """A dim with no divisor on the (8, 128) grid is one tile."""
+    from repro.kernels import amp_fused
+    t = amp_fused._amp_tiles(20, 192)
+    assert t.sub == 20
+    assert t.adj_rows == t.fwd_rows == t.gen_rows == t.red_rows == 20
+    assert t.gen_cols == t.adj_cols == t.fwd_cols == 192
+
+
 # ---------------------------------------------------------------------------
 # the one-generation-per-block guarantee (acceptance criterion)
 # ---------------------------------------------------------------------------
