@@ -13,6 +13,7 @@ import time, so selecting a backend after importing this module works.
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -31,16 +32,61 @@ def interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+#: Lowerings of the kernel projection under ``vmap``: ``shared`` stacks
+#: whose devices share each generated A tile, the ``devices`` they cover,
+#: and ``fallback`` vmaps of a batched seed (one A per device).
+_LOWERINGS = collections.Counter()
+
+
+def projection_lowerings() -> dict:
+    """A copy of the projection's lowering counts (``_LOWERINGS``)."""
+    return {k: _LOWERINGS[k] for k in ("shared", "devices", "fallback")}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_project(s_block: int, rademacher: bool, nb_tile, interpret: bool):
+    """``(x, seed) -> A x`` through the Pallas kernel.  Under ``vmap`` with
+    the seed unbatched (the devices share A) the vmapped x is handed to the
+    kernel as one stack, batch axis leading, and each program generates its
+    A tile once for all devices; nested vmaps fold into that stack.  A
+    batched seed takes Pallas's own batching: one A per device."""
+    def call(x, seed):
+        return ota_project_pallas(x, seed, s_block, rademacher,
+                                  nb_tile=nb_tile, interpret=interpret)
+
+    project = jax.custom_batching.custom_vmap(call)
+
+    @project.def_vmap
+    def _rule(axis_size, in_batched, x, seed):
+        x_batched, seed_batched = in_batched
+        if seed_batched:
+            _LOWERINGS["fallback"] += 1
+            return jax.vmap(call, in_axes=(0 if x_batched else None, 0))(
+                x, seed), True
+        if x.ndim == 3:                  # one device's blocks per entry
+            _LOWERINGS["shared"] += 1
+            _LOWERINGS["devices"] += axis_size
+            return project(x, seed), True
+        lead = x.shape[:2]               # an outer vmap over a stack
+        _LOWERINGS["devices"] += (axis_size - 1) * lead[1]
+        y = project(x.reshape(-1, *x.shape[2:]), seed)
+        return y.reshape(lead + y.shape[1:]), True
+
+    return project
+
+
 @functools.partial(jax.jit, static_argnames=("s_block", "rademacher",
                                              "use_kernel", "nb_tile"))
 def ota_project(x: jnp.ndarray, *, seed, s_block: int,
                 rademacher: bool = True, use_kernel: bool = False,
                 nb_tile: int | None = None):
-    """Blocked forward projection. x: (n_blocks, c) -> (n_blocks, s_block)."""
+    """Blocked forward projection. x: (n_blocks, c) -> (n_blocks, s_block).
+
+    With ``use_kernel`` a ``vmap`` over devices that share the seed runs
+    one kernel over the device stack (:func:`_kernel_project`)."""
     if use_kernel:
-        return ota_project_pallas(x, seed, s_block, rademacher,
-                                  nb_tile=nb_tile,
-                                  interpret=interpret_default())
+        return _kernel_project(s_block, rademacher, nb_tile,
+                               interpret_default())(x, seed)
     return ref.ota_project_ref(x, seed, s_block, rademacher)
 
 

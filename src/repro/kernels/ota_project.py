@@ -7,17 +7,19 @@ VMEM tile of A from a counter-based hash (see kernels/ref.py) *inside* the
 matmul kernel: HBM traffic is O(d + s) and A never exists.
 
 TPU adaptation notes (docs/DESIGN.md §4): each grid program batches
-``nb_tile`` blocks and contracts them with one batched ``dot_general``
-(MXU) instead of a per-block matvec; the VPU generates the next A tile's
-entries from integer hashes while the MXU consumes the previous one
-(software pipelining by the Mosaic compiler); Rademacher entries (one hash
-+ sign) instead of Box-Muller Gaussians.  Tiles follow the TPU block rule
-(8 blocks per program, lane tiles of 128) and the contracted dim is split
-over the grid's last axis, summed into the resident output block, so one
-A tile stays a few MiB at the published c = 4096, s_block = 1024.  The
-seed arrives through SMEM as a *traced* uint32 scalar, so the shard-folded
-seeds of the fully-sharded slice driver (core/distributed.py) lower
-through the same kernels.
+``nb_tile`` blocks, generates their A tile once from integer hashes on the
+VPU, and contracts it with the x tile of every device in the stack it is
+given (the devices share A: the seed is the projector's), one batched f32
+``dot_general`` per device.  At these shapes Mosaic lowers each product to
+VPU multiplies and cross-lane sums that fill the hash's free slots, so
+the program's time is its generation of A (PERF.md §6).
+Rademacher entries (one hash + sign) instead of Box-Muller Gaussians.
+Tiles follow the TPU block rule (8 blocks per program, lane tiles of 128)
+and the contracted dim is split over the grid's last axis, summed into
+the resident output block, so one A tile stays a few MiB at the published
+c = 4096, s_block = 1024.  The seed arrives through SMEM as a *traced*
+uint32 scalar, so the shard-folded seeds of the fully-sharded slice driver
+(core/distributed.py) lower through the same kernels.
 
 Kernels are validated in interpret mode against kernels/ref.py.
 """
@@ -36,6 +38,11 @@ from repro.kernels.ref import _GOLDEN, _M1, _M2
 #: temporaries of the tile live in VMEM beside it, so the tile stays well
 #: under the 16 MiB scoped-VMEM default of a v5e core.
 VMEM_TILE_BYTES = 2 << 20
+
+#: VMEM budget for the x and y blocks, double-buffered, of the devices one
+#: forward program serves beside its A tile: 25 devices at c = 4096,
+#: s_block = 1024; a larger stack splits into groups.
+VMEM_STACK_BYTES = 1 << 20
 
 #: TPU block rule: the last two dims of a block are multiples of
 #: (_SUBLANE, _LANE) or span the whole array dim.
@@ -148,47 +155,75 @@ _PARAMS = pltpu.CompilerParams(
 
 
 # ---------------------------------------------------------------------------
-# forward projection: y[b] = A_b @ x[b],  nb_tile blocks per program
+# forward projection: y[d, b] = A_b @ x[d, b] for a stack of devices d,
+# nb_tile blocks per program, each A tile generated once for all devices
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(seed_ref, x_ref, y_ref, *, nb_tile, s_tile, c_tile, s_block,
-                rademacher):
-    g = pl.program_id(0)                 # block-chunk index
-    i = pl.program_id(1)                 # row-tile index inside s_block
-    k = pl.program_id(2)                 # col-tile index inside c (summed)
+def _fwd_kernel(seed_ref, x_ref, y_ref, *, lead, m_tile, nb_tile, s_tile,
+                c_tile, s_block, rademacher):
+    g = pl.program_id(lead)              # block-chunk index
+    i = pl.program_id(lead + 1)          # row-tile index inside s_block
+    k = pl.program_id(lead + 2)          # col-tile index inside c (summed)
     A = _tile_A(seed_ref[0, 0], jnp.uint32(g * nb_tile),
                 jnp.uint32(i * s_tile), jnp.uint32(k * c_tile), nb_tile,
                 s_tile, c_tile, s_block, rademacher)
-    _accumulate(y_ref, _bdot(A, x_ref[...], 2, 1), k)   # (nb_tile, s_tile)
+    for d in range(m_tile):              # the one A tile, every device's x
+        _accumulate(y_ref.at[d], _bdot(A, x_ref[d], 2, 1), k)
 
 
 def ota_project_pallas(x: jnp.ndarray, seed, s_block: int,
                        rademacher: bool = True, nb_tile: int | None = None,
                        interpret: bool = True) -> jnp.ndarray:
-    """x: (n_blocks, c) float32 -> y: (n_blocks, s_block) float32."""
-    n_blocks, c = x.shape
+    """x: (n_blocks, c) -> y: (n_blocks, s_block), or a stack of devices
+    (m, n_blocks, c) -> (m, n_blocks, s_block), all float32.
+
+    A stack shares A: each program generates its tile once and contracts
+    it with every device's x tile, each product the one a lone device's
+    call makes, so every device's y is bitwise its own call's.  Devices
+    whose x and y blocks outgrow ``VMEM_STACK_BYTES`` split into groups
+    along one more (leading) grid axis, one A tile per group."""
+    if x.ndim == 2:
+        return ota_project_pallas(x[None], seed, s_block, rademacher,
+                                  nb_tile, interpret)[0]
+    m, n_blocks, c = x.shape
     nb_tile = _block_rows(n_blocks, nb_tile)
     s_tile = _legal_tile(s_block, _LANE, _LANE)
     c_tile = _legal_tile(c, max(_LANE, VMEM_TILE_BYTES // 4
                                 // (nb_tile * s_tile)), _LANE)
-    x_p = _pad_blocks(x.astype(jnp.float32), nb_tile)
-    grid = (x_p.shape[0] // nb_tile, s_block // s_tile, c // c_tile)
-    kern = functools.partial(_fwd_kernel, nb_tile=nb_tile, s_tile=s_tile,
-                             c_tile=c_tile, s_block=s_block,
-                             rademacher=rademacher)
+    per_device = 2 * 4 * nb_tile * (c_tile + s_tile)   # double-buffered
+    groups = -(-m // max(1, VMEM_STACK_BYTES // per_device))
+    m_tile = -(-m // groups)
+    pad = ((0, groups * m_tile - m), (0, (-n_blocks) % nb_tile), (0, 0))
+    x_p = x.astype(jnp.float32)
+    if any(p for _, p in pad):
+        x_p = jnp.pad(x_p, pad)
+    grid = ((groups,) if groups > 1 else ()) + (
+        x_p.shape[1] // nb_tile, s_block // s_tile, c // c_tile)
+
+    def x_map(*ids):                     # ([group,] chunk, row, col)
+        return (*(ids[:-3] or (0,)), ids[-3], ids[-1])
+
+    def y_map(*ids):
+        return (*(ids[:-3] or (0,)), ids[-3], ids[-2])
+
+    kern = functools.partial(_fwd_kernel, lead=len(grid) - 3, m_tile=m_tile,
+                             nb_tile=nb_tile, s_tile=s_tile, c_tile=c_tile,
+                             s_block=s_block, rademacher=rademacher)
     y = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((nb_tile, c_tile), lambda g, i, k: (g, k))],
-        out_specs=pl.BlockSpec((nb_tile, s_tile), lambda g, i, k: (g, i)),
-        out_shape=jax.ShapeDtypeStruct((x_p.shape[0], s_block), jnp.float32),
-        compiler_params=_PARAMS,
+                  pl.BlockSpec((m_tile, nb_tile, c_tile), x_map)],
+        out_specs=pl.BlockSpec((m_tile, nb_tile, s_tile), y_map),
+        out_shape=jax.ShapeDtypeStruct(x_p.shape[:2] + (s_block,),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(
+            ("parallel",) * (len(grid) - 1) + ("arbitrary",))),
         interpret=interpret,
         name="ota_project",
     )(_seed_arr(seed), x_p)
-    return y[:n_blocks]
+    return y[:m, :n_blocks]
 
 
 # ---------------------------------------------------------------------------

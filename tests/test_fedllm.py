@@ -107,6 +107,40 @@ def test_kernel_encode_path_on_streamed_chunks():
                                rtol=2e-5, atol=2e-5)
 
 
+def _pallas_calls(jaxpr, name):
+    """Every ``pallas_call`` named ``name`` in ``jaxpr`` and its sub-jaxprs."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"] == name):
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    found += _pallas_calls(sub, name)
+    return found
+
+
+def test_kernel_encode_lowers_one_projection_for_all_devices():
+    """The vmapped per-device encode lowers to ONE ``ota_project`` kernel
+    over the stack of m devices, with no device axis in its grid."""
+    from repro.core.schemes import encode_round
+    fed = _fed(use_kernel=True)
+    key = round_keys(1, 0)[0]
+    g = jnp.zeros((fed.m, fed.chunk_len), jnp.float32)
+    dl = jnp.zeros((fed.m, fed.chunk_len), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda g, dl: encode_round(
+        fed.scheme, g, dl, 0, key, fed.ctx))(g, dl).jaxpr
+    (call,) = _pallas_calls(jaxpr, "ota_project")
+    x = call.invars[1].aval
+    n_blocks = fed.chunk_len // 256
+    assert x.shape[0] == fed.m and x.shape[1] >= n_blocks
+    assert len(call.params["grid_mapping"].grid) == 3
+    assert call.params["grid_mapping"].grid[0] == -(-n_blocks // 8)
+
+
 @pytest.mark.slow
 def test_serve_while_train_demo():
     arch = get_config("smollm_360m").reduced()

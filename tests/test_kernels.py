@@ -104,3 +104,53 @@ def test_ef_sparsify_lazy_interpret_default():
     sr, dr = ref.ef_sparsify_ref(g, d, jnp.float32(0.3))
     np.testing.assert_array_equal(np.asarray(sp), np.asarray(sr))
     assert ops.interpret_default() is True  # CPU test env
+
+
+# ---------------------------------------------------------------------------
+# the forward projection over a stack of devices: one A tile for all
+# ---------------------------------------------------------------------------
+
+
+def _per_device(x, seeds, rademacher):
+    """Today's per-device kernel, one call a device: the bitwise reference."""
+    from repro.kernels.ota_project import ota_project_pallas
+    return np.stack([np.asarray(ota_project_pallas(x[d], seeds[d], 128,
+                                                   rademacher))
+                     for d in range(x.shape[0])])
+
+
+@pytest.mark.parametrize("route", ["stack", "stack_grouped",
+                                   "vmap_shared_seed", "vmap_batched_seed"])
+@pytest.mark.parametrize("m", [1, 2, 3])
+@pytest.mark.parametrize("rademacher", [True, False])
+def test_stacked_projection_bitwise_per_device(route, m, rademacher,
+                                               monkeypatch):
+    """Every device's y from the stacked kernel, or from a vmap of
+    ``ops.ota_project``, is bitwise its own per-device call's; 13 blocks
+    pad to two programs of 8.  A vmap with a shared seed lowers to one
+    shared stack, a batched seed falls back to one A per device."""
+    from repro.kernels import ota_project
+    x = jax.random.normal(jax.random.PRNGKey(m), (m, 13, 256), jnp.float32)
+    seeds = [11] * m
+    before = ops.projection_lowerings()
+    if route.startswith("stack"):
+        if route == "stack_grouped":        # one device per group
+            monkeypatch.setattr(ota_project, "VMEM_STACK_BYTES", 1)
+        y = ota_project.ota_project_pallas(x, 11, 128, rademacher)
+        counts = {"shared": 0, "devices": 0, "fallback": 0}
+    else:
+        jax.clear_caches()                  # count this trace's lowering
+        shared = route == "vmap_shared_seed"
+        if not shared:
+            seeds = [11 + 5 * d for d in range(m)]
+        f = jax.vmap(lambda xd, sd: ops.ota_project(
+            xd, seed=sd, s_block=128, rademacher=rademacher, use_kernel=True),
+            in_axes=(0, None if shared else 0))
+        y = f(x, jnp.uint32(11) if shared else jnp.asarray(seeds, jnp.uint32))
+        counts = ({"shared": 1, "devices": m, "fallback": 0} if shared
+                  else {"shared": 0, "devices": 0, "fallback": 1})
+    after = ops.projection_lowerings()
+    assert {k: after[k] - before[k] for k in after} == counts
+    assert y.shape == (m, 13, 128)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  _per_device(x, seeds, rademacher))

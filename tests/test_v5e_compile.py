@@ -43,6 +43,10 @@ def _cases():
         "ota_project": (
             lambda x, sd: ota_project_pallas(x, sd, S_BLOCK, interpret=False),
             [((N_BLOCKS, C), jnp.float32), seed]),
+        # the streamed encode's m = 2 devices share each A tile
+        "ota_project_stacked": (
+            lambda x, sd: ota_project_pallas(x, sd, S_BLOCK, interpret=False),
+            [((2, N_BLOCKS, C), jnp.float32), seed]),
         "ota_project_t": (
             lambda y, sd: ota_project_t_pallas(y, sd, C, interpret=False),
             [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
