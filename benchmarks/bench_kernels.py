@@ -1,4 +1,4 @@
-"""Kernel micro-benchmarks: blocked projection fwd/adjoint + full AMP decode.
+"""Kernel micro-benchmarks: blocked forward projection + full AMP decode.
 
 Times the jnp (XLA) path against the Pallas kernel path at two problem
 sizes and writes ``BENCH_kernels.json`` at the repo root — the start of the
@@ -66,10 +66,6 @@ def bench_size(name: str, n_blocks: int, c: int, s_block: int,
             "proj_fwd": _time_us(
                 lambda v: ops.ota_project(v, seed=seed, s_block=s_block,
                                           rademacher=True, use_kernel=uk), x),
-            "proj_adj": _time_us(
-                lambda v: ops.ota_project_t(v, seed=seed, c=c,
-                                            rademacher=True, use_kernel=uk),
-                yb),
             "amp_decode": _time_us(amp, yb),
         }
         for op, us in ops_us.items():

@@ -10,8 +10,8 @@ widths (``get_config("smollm_360m")``, no ``.reduced()``):
    OTA-DSGD round (A-DSGD with ``ota_overrides("smollm_360m")``: blocked
    Rademacher projection, s_frac 0.25, k_frac 0.5, block 4096, Pallas
    kernels on), serving a greedy batch from each round's published globals;
-2. kernels: the compiled Pallas projection, adjoint and fused AMP decode
-   against the jnp path on one chunk at c = 4096, s_block = 1024;
+2. kernels: the compiled Pallas projection and fused AMP decode against
+   the jnp path on one chunk at c = 4096, s_block = 1024;
 3. paper: ``run_compiled`` on the paper's own model (d = 7850, M = 25,
    A-DSGD over the Gaussian MAC).
 
@@ -136,9 +136,6 @@ def phase_kernels():
         return ops.ota_project(x, seed=seed, s_block=s_block,
                                use_kernel=use_kernel)
 
-    def adj(y, use_kernel):
-        return ops.ota_project_t(y, seed=seed, c=c, use_kernel=use_kernel)
-
     def amp(y, use_kernel):
         return amp_blocked_core(y, seed, c, iters=20, chunk_blocks=8,
                                 use_kernel=use_kernel)
@@ -147,13 +144,10 @@ def phase_kernels():
         y_ref = jax.block_until_ready(fwd(x, False))
         y = y_ref + 0.01 * jnp.std(y_ref) * jax.random.normal(
             kn, y_ref.shape)
-        t_ref = adj(y, False)
         a_ref = jax.block_until_ready(jax.jit(amp, static_argnums=1)(y,
                                                                      False))
     res = {}
     for name, fn, arg, want, tol in (("ota_project", fwd, x, y_ref, PROJ_TOL),
-                                     ("ota_project_t", adj, y, t_ref,
-                                      PROJ_TOL),
                                      ("amp_decode_fused",
                                       jax.jit(amp, static_argnums=1), y,
                                       a_ref, AMP_TOL)):

@@ -12,11 +12,13 @@ synthetic k-sparse signals.
 
 The blocked variant runs an independent AMP per projection block (the
 block-diagonal A factorises the problem) — fully batched, shardable along d.
-:func:`amp_blocked_core` is the single chunked implementation behind every
-blocked decode: the on-the-fly A chunk is generated exactly ONCE per decode
-(vs 2*iters+1 times for launch-per-op decoding) and consumed by all
-iterations, either as a jnp ``lax.scan`` (XLA path) or inside the fused
-single-launch Pallas kernel (kernels/amp_fused.py, ``use_kernel=True``).
+:func:`amp_blocked_core` is the one chunked decode, run by every kernel
+decode, by the slice driver and by the flat jnp path past the working-set
+budget: the on-the-fly A chunk is generated exactly ONCE per decode (vs
+2*iters+1 times for launch-per-op decoding) and consumed by all iterations,
+either as a jnp ``lax.scan`` (XLA path) or inside the fused single-launch
+Pallas kernel (kernels/amp_fused.py, ``use_kernel=True``).  Within the
+budget the flat jnp path runs :func:`amp_decode_blocked`.
 """
 from __future__ import annotations
 
@@ -167,17 +169,6 @@ def amp_blocked_core(yb: jnp.ndarray, seed, c: int, iters: int = 20,
     return xs.reshape(-1, c)[:n_blocks]
 
 
-def amp_decode_blocked_scan(yb: jnp.ndarray, projector, iters: int = 20,
-                            threshold_mult: float = 1.3,
-                            debias: bool = True) -> jnp.ndarray:
-    """Chunked-scan AMP sized from a :class:`BlockedProjector` (the jnp
-    analogue of the fused kernel; see :func:`amp_blocked_core`)."""
-    xb = amp_blocked_core(yb, projector.seed, projector.block_size, iters,
-                          projector.chunk_blocks, threshold_mult, debias,
-                          projector.rademacher)
-    return projector.from_blocks(xb)
-
-
 def amp_decode(y_flat: jnp.ndarray, projector, iters: int = 20,
                threshold_mult: float = 1.3) -> jnp.ndarray:
     """Dispatch on projector type; y_flat has projector.out_dim entries."""
@@ -187,12 +178,12 @@ def amp_decode(y_flat: jnp.ndarray, projector, iters: int = 20,
                                 threshold_mult)
     assert isinstance(projector, BlockedProjector)
     yb = y_flat.reshape(projector.n_blocks, projector.s_block)
-    if projector.use_kernel:
+    if projector.use_kernel or projector.n_blocks > projector.chunk_blocks:
+        chunk = (projector.kernel_nb_tile if projector.use_kernel
+                 else projector.chunk_blocks)
         xb = amp_blocked_core(yb, projector.seed, projector.block_size,
-                              iters, projector.kernel_nb_tile,
-                              threshold_mult, rademacher=projector.rademacher,
-                              use_kernel=True)
+                              iters, chunk, threshold_mult,
+                              rademacher=projector.rademacher,
+                              use_kernel=projector.use_kernel)
         return projector.from_blocks(xb)
-    if projector.n_blocks > projector.chunk_blocks:
-        return amp_decode_blocked_scan(yb, projector, iters, threshold_mult)
     return amp_decode_blocked(yb, projector, iters, threshold_mult)

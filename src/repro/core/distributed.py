@@ -16,12 +16,13 @@ slots are scalar psums.  Per-shard measurement matrices derive from a
 shard-folded seed (the PS uses the same fold — consistency by construction).
 No d-sized tensor is ever replicated, gathered, or scanned across shards.
 
-The helpers :func:`proj_forward` / :func:`amp_blocked` are the traced-seed
-blocked projection + AMP realisation: a chunked jnp scan by default, or the
-chunk-batched projection kernels (kernels/ota_project.py) and the fused
-single-launch AMP kernel (kernels/amp_fused.py) when the scheme passes
-``use_kernel=True`` — both kernels take the traced shard-folded seed
-through an SMEM operand, so PS and devices stay consistent by construction.
+The A-DSGD slice hooks project and decode through the flat path's own
+blocked codec, :func:`repro.core.projection.blocked_projection` and
+:func:`repro.core.amp.amp_blocked_core`, with the traced shard-folded seed:
+jnp scans over chunks of ``MACContext.chunk_blocks`` blocks, or the Pallas
+projection and fused AMP kernels when ``OTAConfig.use_kernel`` is set.
+Both kernels take the seed through an SMEM operand, so PS and devices stay
+consistent by construction.
 """
 from __future__ import annotations
 
@@ -31,62 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import channel
-from repro.kernels import ref
 from repro.tracing import stage
-
-
-# ---------------------------------------------------------------------------
-# traced-seed blocked projection + AMP (the jnp/XLA realisation)
-# ---------------------------------------------------------------------------
-
-
-def proj_forward(xb: jnp.ndarray, seed_u32, s_block: int,
-                 chunk_blocks: int, use_kernel: bool = False) -> jnp.ndarray:
-    """xb (n_blocks, c) -> (n_blocks, s_block); A generated per chunk.
-
-    ``use_kernel=True`` lowers through the chunk-batched Pallas projection
-    kernel (kernels/ota_project.py) — the traced shard-folded seed passes
-    straight through its SMEM operand.
-    """
-    n_blocks, c = xb.shape
-    if use_kernel:
-        from repro.kernels import ops
-        return ops.ota_project(xb, seed=seed_u32, s_block=s_block,
-                               rademacher=True, use_kernel=True)
-    ni = min(chunk_blocks, n_blocks)
-    pad = (-n_blocks) % ni
-    xb_p = jnp.pad(xb, ((0, pad), (0, 0)))
-    n_outer = (n_blocks + pad) // ni
-    xs = xb_p.reshape(n_outer, ni, c)
-    ids = jnp.arange(n_outer * ni, dtype=jnp.uint32).reshape(n_outer, ni)
-
-    def body(_, inp):
-        ids_c, x_c = inp
-        A = jax.vmap(lambda b: ref.block_matrix_ref(seed_u32, b, s_block,
-                                                    c, True))(ids_c)
-        return None, jnp.einsum("isc,ic->is", A, x_c)
-
-    _, ys = jax.lax.scan(body, None, (ids, xs))
-    return ys.reshape(-1, s_block)[:n_blocks]
-
-
-def amp_blocked(yb: jnp.ndarray, seed_u32, c: int, iters: int,
-                chunk_blocks: int, threshold_mult: float = 1.3,
-                debias: bool = True, id_offset=0,
-                use_kernel: bool = False) -> jnp.ndarray:
-    """Per-block AMP with traced seed; A generated ONCE per block per decode.
-
-    Thin re-export of :func:`repro.core.amp.amp_blocked_core` (the single
-    chunked implementation: jnp scan, or the fused single-launch Pallas
-    kernel when ``use_kernel=True``).
-
-    id_offset (traced ok): global index of this slice's first block — lets a
-    device decode a sub-range of blocks with the encoder's global block ids.
-    """
-    from repro.core.amp import amp_blocked_core
-    return amp_blocked_core(yb, seed_u32, c, iters, chunk_blocks,
-                            threshold_mult, debias, rademacher=True,
-                            id_offset=id_offset, use_kernel=use_kernel)
 
 
 def psum_all(x, axes: Sequence[str]):

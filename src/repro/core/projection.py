@@ -86,6 +86,42 @@ def _chunk_blocks_for(s_block: int, c: int, budget_bytes: int = 128 << 20) -> in
     return max(1, budget_bytes // max(s_block * c * 4, 1))
 
 
+def blocked_projection(xb: jnp.ndarray, seed, s_block: int,
+                       chunk_blocks: int, rademacher: bool = True,
+                       use_kernel: bool = False) -> jnp.ndarray:
+    """xb (n_blocks, c) -> (n_blocks, s_block): block b times its A_b, each
+    A_b generated on the fly from ``seed`` (a python int or a traced uint32,
+    e.g. the slice driver's shard-folded seed).
+
+    ``use_kernel`` runs the Pallas projection kernel.  The call stays a
+    direct call of ``ops.ota_project``, so that a ``vmap`` over devices
+    that share the seed reaches its rule that generates each A tile once
+    for all of them.  Otherwise up to ``chunk_blocks`` blocks run the jnp
+    oracle at once; past that a scan generates and consumes ``chunk_blocks``
+    blocks' A at a time, bounding the A working set (the jnp analogue of the
+    kernel's VMEM tiling, docs/DESIGN.md §4.1).
+    """
+    n_blocks, c = xb.shape
+    if use_kernel or n_blocks <= chunk_blocks:
+        return ops.ota_project(xb, seed=seed, s_block=s_block,
+                               rademacher=rademacher, use_kernel=use_kernel)
+    pad = (-n_blocks) % chunk_blocks
+    xs = jnp.pad(xb, ((0, pad), (0, 0))).reshape(-1, chunk_blocks, c)
+    ids = jnp.arange(xs.shape[0] * chunk_blocks,
+                     dtype=jnp.uint32).reshape(-1, chunk_blocks)
+
+    def gen(b):
+        return ref.block_matrix_ref(seed, b, s_block, c, rademacher)
+
+    def body(_, inp):
+        ids_c, x_c = inp
+        A = jax.vmap(gen)(ids_c)                   # (chunk_blocks, s_block, c)
+        return None, jnp.einsum("isc,ic->is", A, x_c)
+
+    _, ys = jax.lax.scan(body, None, (ids, xs))
+    return ys.reshape(-1, s_block)[:n_blocks]
+
+
 @dataclass(frozen=True)
 class BlockedProjector:
     d: int
@@ -133,53 +169,15 @@ class BlockedProjector:
         return self.project_blocks(self.to_blocks(v)).reshape(-1)
 
     def project_blocks(self, xb: jnp.ndarray) -> jnp.ndarray:
-        if not self.use_kernel and xb.shape[0] > self.chunk_blocks:
-            return self._scan_op(xb, transpose=False)
-        return ops.ota_project(xb, seed=self.seed, s_block=self.s_block,
-                               rademacher=self.rademacher,
-                               use_kernel=self.use_kernel)
-
-    def project_t(self, y_flat: jnp.ndarray) -> jnp.ndarray:
-        yb = y_flat.reshape(self.n_blocks, self.s_block)
-        return self.from_blocks(self.project_t_blocks(yb))
+        return blocked_projection(xb, self.seed, self.s_block,
+                                  self.chunk_blocks, self.rademacher,
+                                  self.use_kernel)
 
     def project_t_blocks(self, yb: jnp.ndarray) -> jnp.ndarray:
-        if not self.use_kernel and yb.shape[0] > self.chunk_blocks:
-            return self._scan_op(yb, transpose=True)
-        return ops.ota_project_t(yb, seed=self.seed, c=self.block_size,
-                                 rademacher=self.rademacher,
-                                 use_kernel=self.use_kernel)
-
-    def _scan_op(self, xb: jnp.ndarray, transpose: bool) -> jnp.ndarray:
-        """Chunked scan: generate each A chunk on the fly and consume it.
-
-        The jnp analogue of the Pallas kernel's VMEM tiling — bounds the
-        A working set to ``chunk_blocks`` blocks (docs/DESIGN.md §4.1).
-        """
-        n_blocks = xb.shape[0]
-        ni = self.chunk_blocks
-        pad = (-n_blocks) % ni
-        xb_p = jnp.pad(xb, ((0, pad), (0, 0)))
-        n_outer = (n_blocks + pad) // ni
-        xs = xb_p.reshape(n_outer, ni, xb.shape[1])
-        ids = jnp.arange(n_outer * ni, dtype=jnp.uint32).reshape(n_outer, ni)
-
-        def gen(b):
-            return ref.block_matrix_ref(self.seed, b, self.s_block,
-                                        self.block_size, self.rademacher)
-
-        def body(_, inp):
-            ids_c, x_c = inp
-            A = jax.vmap(gen)(ids_c)               # (ni, s_block, c)
-            if transpose:
-                y = jnp.einsum("isc,is->ic", A, x_c)
-            else:
-                y = jnp.einsum("isc,ic->is", A, x_c)
-            return None, y
-
-        _, ys = jax.lax.scan(body, None, (ids, xs))
-        out_w = self.block_size if transpose else self.s_block
-        return ys.reshape(-1, out_w)[:n_blocks]
+        """The adjoint, all blocks' A at once (the jnp oracle): the one
+        :func:`repro.core.amp.amp_decode_blocked` runs within the budget."""
+        return ref.ota_project_t_ref(yb, self.seed, self.block_size,
+                                     self.rademacher)
 
     def block_matrix(self, b: int) -> jnp.ndarray:
         """Materialise one block (tests only)."""

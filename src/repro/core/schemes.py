@@ -63,8 +63,10 @@ import jax.numpy as jnp
 
 from repro.configs.base import OTAConfig
 from repro.core import channel, compression, fading, geometry, power
-from repro.core.amp import amp_decode
-from repro.core.projection import DenseProjector, make_projector
+from repro.core.amp import amp_blocked_core, amp_decode
+from repro.core.projection import (
+    DenseProjector, blocked_projection, make_projector,
+)
 from repro.kernels import ops, ref
 from repro.robust import faults
 from repro.tracing import stage
@@ -99,7 +101,6 @@ class MACContext:
     chunk_blocks: int = 8                        # A-matrix working set
     frame_dtype: Any = None                      # psum analog bodies in bf16
     shard_decode: bool = False                   # split PS AMP across devices
-    use_kernel: bool = False                     # Pallas projection/AMP path
     # hierarchical MAC: each edge-site group receives its own AWGN (the
     # partial OTA sums combine over the backhaul; repro.population.hierarchy)
     site_mac: bool = False
@@ -488,32 +489,19 @@ class ADSGDScheme(Scheme):
                 f"d={d} to size a different gradient")
         return self.projector.out_dim + 2
 
-    def _projector_for(self, ctx: Optional[MACContext]):
-        """The projector honouring the MACContext's use_kernel override
-        (dense projectors have no kernel path; cfg.use_kernel is baked into
-        the cached projector, so only an upgrade needs a copy)."""
-        proj = self.projector
-        if (ctx is not None and ctx.use_kernel
-                and not isinstance(proj, DenseProjector)
-                and not proj.use_kernel):
-            proj = dataclasses.replace(proj, use_kernel=True)
-        return proj
-
     def encode(self, g, state, step, key, ctx=None):
         cfg = self.cfg
         g = g.astype(jnp.float32)
         p_t = self.p_t(step, ctx.p_factor if ctx is not None else 1.0)
         g_ec = g + state.astype(jnp.float32)
-        projector = self._projector_for(ctx)
+        projector = self.projector
         if isinstance(projector, DenseProjector):
             g_sp = compression.top_k_sparsify(g_ec, self.k)
             new_state = g_ec - g_sp
         else:
             tau = compression.sampled_topk_threshold(g_ec, self.k, key)
             g_sp, new_state = ops.ef_sparsify(
-                g, state.astype(jnp.float32), tau,
-                use_kernel=self._use_kernel(ctx) if ctx is not None
-                else cfg.use_kernel)
+                g, state.astype(jnp.float32), tau, use_kernel=cfg.use_kernel)
         g_tilde = projector.project(g_sp)
         use_mr = (jnp.asarray(step) < cfg.mean_removal_steps)
         frame, alpha = channel.make_frame(g_tilde, p_t, use_mr)
@@ -524,8 +512,7 @@ class ADSGDScheme(Scheme):
     def decode(self, y, step, ctx=None):
         use_mr = (jnp.asarray(step) < self.cfg.mean_removal_steps)
         y_body = channel.ps_normalize(y, use_mr)
-        return amp_decode(y_body, self._projector_for(ctx),
-                          self.cfg.amp_iters)
+        return amp_decode(y_body, self.projector, self.cfg.amp_iters)
 
     def silent_state(self, g, state, new_state):
         # a device that could not transmit (deep fade, mid-round dropout)
@@ -546,12 +533,8 @@ class ADSGDScheme(Scheme):
         return ref.splitmix32(jnp.uint32(self.cfg.seed)
                               ^ shard_idx.astype(jnp.uint32)), shard_idx
 
-    def _use_kernel(self, ctx: MACContext) -> bool:
-        """Pallas knob: OTAConfig.use_kernel, or the MACContext override."""
-        return bool(self.cfg.use_kernel) or ctx.use_kernel
-
     def encode_slice(self, g_slice, state_slice, step, key, ctx):
-        from repro.core.distributed import proj_forward, psum_all
+        from repro.core.distributed import psum_all
         cfg = self.cfg
         d_pad = ctx.d_pad
         d_local = g_slice.shape[0]
@@ -578,9 +561,10 @@ class ADSGDScheme(Scheme):
         s_block = max(2, int(round(cfg.s_frac * c)))
         n_blocks_local = d_local // c
         seed_u32, _ = self._slice_seed(ctx)
-        yb = proj_forward(g_sp.reshape(n_blocks_local, c), seed_u32, s_block,
-                          ctx.chunk_blocks,
-                          use_kernel=self._use_kernel(ctx))  # (nb_local, s_b)
+        # (nb_local, s_block); the slice path always draws Rademacher A
+        yb = blocked_projection(g_sp.reshape(n_blocks_local, c), seed_u32,
+                                s_block, ctx.chunk_blocks, rademacher=True,
+                                use_kernel=cfg.use_kernel)
 
         # --- power scaling (paper eq. 13/22; scalars psum'd over shards) ---
         # ctx.p_factor carries this device's fading received-power factor
@@ -599,7 +583,6 @@ class ADSGDScheme(Scheme):
         return frame, new_state, metrics
 
     def decode_slice(self, y, step, ctx):
-        from repro.core.distributed import amp_blocked
         cfg = self.cfg
         body, slots = y["body"], y["slots"]
         use_mr = (jnp.asarray(step)
@@ -611,7 +594,6 @@ class ADSGDScheme(Scheme):
         scale = jnp.where(slots[1] > channel.SCALE_SLOT_FLOOR, slots[1], 1.0)
         y_norm = (body + use_mr * slots[0]) / scale
         seed_u32, _ = self._slice_seed(ctx)
-        use_kernel = self._use_kernel(ctx)
         c = cfg.block_size
         if ctx.shard_decode and ctx.device_axes:
             # the y slice is identical on every device row after the psum —
@@ -629,15 +611,15 @@ class ADSGDScheme(Scheme):
             y_p = jnp.pad(y_norm, ((0, nb_pad - nb), (0, 0)))
             per = nb_pad // n_rows
             y_mine = jax.lax.dynamic_slice_in_dim(y_p, row_idx * per, per, 0)
-            x_mine = amp_blocked(y_mine, seed_u32, c, cfg.amp_iters,
-                                 ctx.chunk_blocks,
-                                 id_offset=(row_idx * per).astype(jnp.uint32),
-                                 use_kernel=use_kernel)
+            x_mine = amp_blocked_core(
+                y_mine, seed_u32, c, cfg.amp_iters, ctx.chunk_blocks,
+                id_offset=(row_idx * per).astype(jnp.uint32),
+                use_kernel=cfg.use_kernel)
             xg = jax.lax.all_gather(x_mine, ctx.device_axes, tiled=True)
             return xg[:nb].reshape(-1)
-        return amp_blocked(y_norm, seed_u32, c, cfg.amp_iters,
-                           ctx.chunk_blocks,
-                           use_kernel=use_kernel).reshape(-1)
+        return amp_blocked_core(y_norm, seed_u32, c, cfg.amp_iters,
+                                ctx.chunk_blocks,
+                                use_kernel=cfg.use_kernel).reshape(-1)
 
 
 # ---------------------------------------------------------------------------

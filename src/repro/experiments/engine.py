@@ -75,7 +75,6 @@ class Experiment:
     local_lr: float = 0.1
     momentum_correction: float = 0.0
     seed: int = 0
-    use_kernel: bool = False     # Pallas projection/AMP inside the scan
     guard: Optional[guards.GuardConfig] = None   # round guardrails (§10)
 
 
@@ -280,9 +279,8 @@ class CompiledExperiment:
         self.opt = Optimizer(name=exp.optimizer, lr=exp.lr)
         self.xd, self.yd = jnp.asarray(x_dev), jnp.asarray(y_dev)
         self.xt, self.yt = jnp.asarray(x_test), jnp.asarray(y_test)
-        self.ctx = MACContext(
-            m=m, fading=exp.cfg.fading, csi=self.scheme.csi,
-            use_kernel=exp.use_kernel or exp.cfg.use_kernel)
+        self.ctx = MACContext(m=m, fading=exp.cfg.fading,
+                              csi=self.scheme.csi)
 
     # ------------------------------------------------------------- pieces
     def _carry0(self):
@@ -527,7 +525,6 @@ def run_compiled(x_dev: np.ndarray, y_dev: np.ndarray, x_test: np.ndarray,
                  lr: float = 1e-3, eval_every: int = 10, seed: int = 0,
                  optimizer: str = "adam", local_steps: int = 1,
                  local_lr: float = 0.1, momentum_correction: float = 0.0,
-                 use_kernel: bool = False,
                  guard: Optional[guards.GuardConfig] = None,
                  checkpoint_dir: Optional[str] = None,
                  checkpoint_every: int = 0, resume: bool = False,
@@ -551,7 +548,7 @@ def run_compiled(x_dev: np.ndarray, y_dev: np.ndarray, x_test: np.ndarray,
     exp = Experiment(cfg=cfg, steps=steps, lr=lr, eval_every=eval_every,
                      optimizer=optimizer, local_steps=local_steps,
                      local_lr=local_lr, momentum_correction=momentum_correction,
-                     seed=seed, use_kernel=use_kernel, guard=guard)
+                     seed=seed, guard=guard)
     ce = CompiledExperiment(x_dev, y_dev, x_test, y_test, exp)
     keys = round_keys(steps, seed)
     if checkpoint_dir is not None and checkpoint_every > 0:

@@ -138,7 +138,7 @@ def _validate_axes(axes: Dict[str, Sequence], base: OTAConfig) -> None:
 def run_sweep(dev_data, test_data, base: OTAConfig,
               axes: Dict[str, Sequence], *, steps: int, lr: float = 1e-3,
               eval_every: int = 10, optimizer: str = "adam", seed: int = 0,
-              local_lr: float = 0.1, use_kernel: bool = False) -> SweepResult:
+              local_lr: float = 0.1) -> SweepResult:
     """Run the cartesian grid of ``axes`` over ``base``.
 
     dev_data = (x_dev (M, B, dim), y_dev), test_data = (x_test, y_test).
@@ -168,8 +168,7 @@ def run_sweep(dev_data, test_data, base: OTAConfig,
         static_d = dict(zip(static_names, static_vals))
         cfg = dataclasses.replace(base, **static_d)
         exp = Experiment(cfg=cfg, steps=steps, lr=lr, eval_every=eval_every,
-                         optimizer=optimizer, seed=seed, local_lr=local_lr,
-                         use_kernel=use_kernel)
+                         optimizer=optimizer, seed=seed, local_lr=local_lr)
         ce = CompiledExperiment(xd, yd, xt, yt, exp)
         digital = hasattr(ce.scheme, "q_sched")
         if "local_epochs" in axes:
@@ -250,8 +249,7 @@ def run_population_sweep(data, test_data, base: OTAConfig, base_pop,
                          axes: Dict[str, Sequence], *, steps: int,
                          lr: float = 1e-3, eval_every: int = 10,
                          optimizer: str = "adam", seed: int = 0,
-                         local_lr: float = 0.1,
-                         use_kernel: bool = False) -> SweepResult:
+                         local_lr: float = 0.1) -> SweepResult:
     """:func:`run_sweep` over the sampled-cohort population engine.
 
     ``data`` is a :class:`repro.population.PopulationData`; ``base_pop`` a
@@ -307,8 +305,7 @@ def run_population_sweep(data, test_data, base: OTAConfig, base_pop,
         exp = PopulationExperiment(cfg=cfg, pop=pop, steps=steps, lr=lr,
                                    eval_every=eval_every,
                                    optimizer=optimizer, seed=seed,
-                                   local_lr=local_lr,
-                                   use_kernel=use_kernel)
+                                   local_lr=local_lr)
         cp = CompiledPopulation(data, xt, yt, exp)
         digital = hasattr(cp.scheme, "q_sched")
         if "local_epochs" in axes:

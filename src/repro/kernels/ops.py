@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.amp_fused import amp_decode_fused_pallas
 from repro.kernels.ef_sparsify import ef_sparsify_pallas
-from repro.kernels.ota_project import ota_project_pallas, ota_project_t_pallas
+from repro.kernels.ota_project import ota_project_pallas
 
 
 def interpret_default() -> bool:
@@ -88,19 +88,6 @@ def ota_project(x: jnp.ndarray, *, seed, s_block: int,
         return _kernel_project(s_block, rademacher, nb_tile,
                                interpret_default())(x, seed)
     return ref.ota_project_ref(x, seed, s_block, rademacher)
-
-
-@functools.partial(jax.jit, static_argnames=("c", "rademacher",
-                                             "use_kernel", "nb_tile"))
-def ota_project_t(y: jnp.ndarray, *, seed, c: int,
-                  rademacher: bool = True, use_kernel: bool = False,
-                  nb_tile: int | None = None):
-    """Blocked transpose projection. y: (n_blocks, s_block) -> (n_blocks, c)."""
-    if use_kernel:
-        return ota_project_t_pallas(y, seed, c, rademacher,
-                                    nb_tile=nb_tile,
-                                    interpret=interpret_default())
-    return ref.ota_project_t_ref(y, seed, c, rademacher)
 
 
 @functools.partial(jax.jit, static_argnames=("c", "iters", "threshold_mult",

@@ -1,8 +1,8 @@
-"""Pallas TPU kernels for the on-the-fly blocked compressive projection.
+"""Pallas TPU kernel for the on-the-fly blocked compressive projection.
 
 The paper (§IV) projects each device's sparsified gradient with a shared
 pseudo-random matrix ``A``.  At framework scale A cannot live in HBM
-(s x d = O(1e20) entries for a 100B model), so these kernels generate each
+(s x d = O(1e20) entries for a 100B model), so this kernel generates each
 VMEM tile of A from a counter-based hash (see kernels/ref.py) *inside* the
 matmul kernel: HBM traffic is O(d + s) and A never exists.
 
@@ -19,9 +19,11 @@ and the contracted dim is split over the grid's last axis, summed into
 the resident output block, so one A tile stays a few MiB at the published
 c = 4096, s_block = 1024.  The seed arrives through SMEM as a *traced*
 uint32 scalar, so the shard-folded seeds of the fully-sharded slice driver
-(core/distributed.py) lower through the same kernels.
+(core/distributed.py) lower through the same kernel.  The kernel is the
+forward projection only: the adjoint that AMP needs runs inside the fused
+decode (kernels/amp_fused.py).
 
-Kernels are validated in interpret mode against kernels/ref.py.
+Validated in interpret mode against kernels/ref.py.
 """
 from __future__ import annotations
 
@@ -150,10 +152,6 @@ def _seed_arr(seed) -> jnp.ndarray:
     return jnp.asarray(seed, jnp.uint32).reshape(1, 1)
 
 
-_PARAMS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-
 # ---------------------------------------------------------------------------
 # forward projection: y[d, b] = A_b @ x[d, b] for a stack of devices d,
 # nb_tile blocks per program, each A tile generated once for all devices
@@ -224,48 +222,3 @@ def ota_project_pallas(x: jnp.ndarray, seed, s_block: int,
         name="ota_project",
     )(_seed_arr(seed), x_p)
     return y[:m, :n_blocks]
-
-
-# ---------------------------------------------------------------------------
-# transpose projection: r[b] = A_b^T @ y[b]   (AMP's adjoint step)
-# ---------------------------------------------------------------------------
-
-
-def _t_kernel(seed_ref, y_ref, o_ref, *, nb_tile, s_tile, c_tile, s_block,
-              rademacher):
-    g = pl.program_id(0)
-    j = pl.program_id(1)                 # col-tile index inside c
-    k = pl.program_id(2)                 # row-tile index inside s (summed)
-    A = _tile_A(seed_ref[0, 0], jnp.uint32(g * nb_tile),
-                jnp.uint32(k * s_tile), jnp.uint32(j * c_tile), nb_tile,
-                s_tile, c_tile, s_block, rademacher)
-    y = y_ref[...][:, None, :]            # (nb_tile, 1, s_tile)
-    _accumulate(o_ref, _bdot(y, A, 2, 1)[:, 0, :], k)   # (nb_tile, c_tile)
-
-
-def ota_project_t_pallas(y: jnp.ndarray, seed, c: int,
-                         rademacher: bool = True, nb_tile: int | None = None,
-                         interpret: bool = True) -> jnp.ndarray:
-    """y: (n_blocks, s_block) float32 -> (n_blocks, c) float32."""
-    n_blocks, s_block = y.shape
-    nb_tile = _block_rows(n_blocks, nb_tile)
-    c_tile = _legal_tile(c, 4 * _LANE, _LANE)
-    s_tile = _legal_tile(s_block, max(_LANE, VMEM_TILE_BYTES // 4
-                                      // (nb_tile * c_tile)), _LANE)
-    y_p = _pad_blocks(y.astype(jnp.float32), nb_tile)
-    grid = (y_p.shape[0] // nb_tile, c // c_tile, s_block // s_tile)
-    kern = functools.partial(_t_kernel, nb_tile=nb_tile, s_tile=s_tile,
-                             c_tile=c_tile, s_block=s_block,
-                             rademacher=rademacher)
-    o = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((nb_tile, s_tile), lambda g, j, k: (g, k))],
-        out_specs=pl.BlockSpec((nb_tile, c_tile), lambda g, j, k: (g, j)),
-        out_shape=jax.ShapeDtypeStruct((y_p.shape[0], c), jnp.float32),
-        compiler_params=_PARAMS,
-        interpret=interpret,
-        name="ota_project_t",
-    )(_seed_arr(seed), y_p)
-    return o[:n_blocks]

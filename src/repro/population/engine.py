@@ -191,7 +191,6 @@ class PopulationExperiment:
     local_steps: int = 1
     local_lr: float = 0.1
     seed: int = 0
-    use_kernel: bool = False
     guard: Optional[guards.GuardConfig] = None
 
 
@@ -228,9 +227,8 @@ class CompiledPopulation:
         self._grad_fn = flat_grad_fn(self.unravel)
         self.opt = Optimizer(name=exp.optimizer, lr=exp.lr)
         self.xt, self.yt = jnp.asarray(x_test), jnp.asarray(y_test)
-        self.ctx = MACContext(
-            m=pop.k_cohort, fading=exp.cfg.fading, csi=self.scheme.csi,
-            use_kernel=exp.use_kernel or exp.cfg.use_kernel)
+        self.ctx = MACContext(m=pop.k_cohort, fading=exp.cfg.fading,
+                              csi=self.scheme.csi)
         self.pstate0 = init_population(
             pop, self.d, exp.steps, dtype=jnp.dtype(exp.cfg.state_dtype))
         # FedDyn duals are persistent per-device state, banked exactly like
@@ -418,7 +416,7 @@ def run_population(data: PopulationData, x_test, y_test, cfg: OTAConfig,
                    pop: PopulationConfig, steps: int, lr: float = 1e-3,
                    eval_every: int = 10, seed: int = 0,
                    optimizer: str = "adam", local_steps: int = 1,
-                   local_lr: float = 0.1, use_kernel: bool = False,
+                   local_lr: float = 0.1,
                    guard: Optional[guards.GuardConfig] = None,
                    checkpoint_dir: Optional[str] = None,
                    checkpoint_every: int = 0, resume: bool = False,
@@ -434,7 +432,7 @@ def run_population(data: PopulationData, x_test, y_test, cfg: OTAConfig,
     exp = PopulationExperiment(cfg=cfg, pop=pop, steps=steps, lr=lr,
                                eval_every=eval_every, optimizer=optimizer,
                                local_steps=local_steps, local_lr=local_lr,
-                               seed=seed, use_kernel=use_kernel, guard=guard)
+                               seed=seed, guard=guard)
     cp = CompiledPopulation(data, x_test, y_test, exp)
     keys = round_keys(steps, seed)
     if checkpoint_dir is not None and checkpoint_every > 0:

@@ -200,8 +200,7 @@ class CompiledFedLLM:
         self.d_pad = self.n_chunks * self.chunk_len
         self.scheme = get_scheme(self.ota, self.chunk_len, self.m)
         self.ctx = MACContext(m=self.m, fading=self.ota.fading,
-                              csi=self.scheme.csi,
-                              use_kernel=self.ota.use_kernel)
+                              csi=self.scheme.csi)
         self.opt = make_optimizer(self.train_cfg)
         self.compute_dtype = jnp.dtype(self.train_cfg.compute_dtype)
 

@@ -150,7 +150,7 @@ def make_train_step(arch: ArchConfig, train_cfg: TrainConfig, ota: OTAConfig,
         fading=ota.fading, csi=scheme.csi, d_pad=d_pad,
         frame_dtype=(jnp.dtype(ota.frame_dtype)
                      if ota.frame_dtype != "float32" else None),
-        shard_decode=ota.shard_decode, use_kernel=ota.use_kernel)
+        shard_decode=ota.shard_decode)
     inner_spec = P(auto_axes) if auto_axes else P()
 
     # ---------------- phase 1: per-device grads ---------------------------
@@ -328,13 +328,13 @@ def make_train_step_sliced(arch: ArchConfig, train_cfg: TrainConfig,
         groups=groups_t, fading=ota.fading, csi=scheme.csi,
         d_pad=d_sh_pad * model_size,
         p_scale=p_share_sh, frame_dtype=frame_dtype,
-        shard_decode=ota.shard_decode, use_kernel=ota.use_kernel)
+        shard_decode=ota.shard_decode)
     ctx_rep = MACContext(
         m=m_eff, device_axes=ota_axes, shard_axes=(),
         groups=groups_t, fading=ota.fading, csi=scheme.csi,
         d_pad=d_rep_pad,
         p_scale=1.0 - p_share_sh, key_salt=1789, frame_dtype=frame_dtype,
-        shard_decode=ota.shard_decode, use_kernel=ota.use_kernel)
+        shard_decode=ota.shard_decode)
 
     # ---------------- phase 1: per-device grads (tree out) ----------------
     @stage("grads")

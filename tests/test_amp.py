@@ -3,8 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.amp import (amp_decode, amp_decode_blocked,
-                            amp_decode_blocked_scan, amp_decode_dense)
+from repro.core import projection
+from repro.core.amp import amp_decode, amp_decode_blocked, amp_decode_dense
 from repro.core.projection import BlockedProjector, DenseProjector
 
 
@@ -34,7 +34,7 @@ def test_amp_noise_robust():
     assert rel < 0.15, rel
 
 
-def test_amp_blocked_recovery_and_scan_equivalence():
+def test_amp_blocked_recovery_and_scan_equivalence(monkeypatch):
     d, c, sb = 4096, 256, 128
     proj = BlockedProjector(d=d, block_size=c, s_block=sb, seed=5)
     # per-block sparse signal (k_b ~ s_b/4)
@@ -46,10 +46,13 @@ def test_amp_blocked_recovery_and_scan_equivalence():
     xh = amp_decode(y, proj, iters=30)
     rel = float(jnp.linalg.norm(xh - x) / jnp.linalg.norm(x))
     assert rel < 0.1, rel
-    # the chunked-scan decoder matches the batched one
+    # the chunked-scan decoder matches the batched one: past the A
+    # working-set budget (cut to 4 blocks) amp_decode runs the scan
     yb = y.reshape(proj.n_blocks, sb)
-    x_scan = amp_decode_blocked_scan(yb, proj, iters=30)
     x_batch = amp_decode_blocked(yb, proj, iters=30)
+    monkeypatch.setattr(projection, "_chunk_blocks_for", lambda *a, **k: 4)
+    assert proj.n_blocks > proj.chunk_blocks
+    x_scan = amp_decode(y, proj, iters=30)
     np.testing.assert_allclose(np.asarray(x_scan), np.asarray(x_batch),
                                rtol=1e-4, atol=1e-5)
 

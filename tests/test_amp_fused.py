@@ -5,9 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.amp import (amp_blocked_core, amp_decode, amp_decode_blocked,
-                            amp_decode_blocked_scan)
-from repro.core.projection import BlockedProjector
+from repro.core.amp import amp_blocked_core, amp_decode, amp_decode_blocked
+from repro.core.projection import BlockedProjector, blocked_projection
 from repro.kernels import ops, ref
 
 
@@ -37,11 +36,16 @@ def test_batched_projection_exact_rademacher(nb, c, sb):
     yr = ops.ota_project(x, seed=11, s_block=sb, rademacher=True,
                          use_kernel=False)
     np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))
+    # the adjoint oracle reads the kernel's own A: the kernel applied to the
+    # basis vectors gives each A_b column by column, exactly
     y = jax.random.normal(jax.random.PRNGKey(nb + 7), (nb, sb), jnp.float32)
-    tk = ops.ota_project_t(y, seed=11, c=c, rademacher=True, use_kernel=True)
-    tr = ops.ota_project_t(y, seed=11, c=c, rademacher=True,
-                           use_kernel=False)
-    np.testing.assert_array_equal(np.asarray(tk), np.asarray(tr))
+    cols = jax.vmap(lambda e: ops.ota_project(
+        jnp.broadcast_to(e, (nb, c)), seed=11, s_block=sb, rademacher=True,
+        use_kernel=True))(jnp.eye(c, dtype=jnp.float32))   # (c, nb, sb)
+    a_kernel = jnp.transpose(cols, (1, 2, 0))              # (nb, sb, c)
+    tr = ref.ota_project_t_ref(y, 11, c, rademacher=True)
+    want = jax.vmap(lambda a, v: a.T @ v)(a_kernel, y)
+    np.testing.assert_array_equal(np.asarray(tr), np.asarray(want))
 
 
 def test_projection_kernel_traced_seed():
@@ -57,6 +61,25 @@ def test_projection_kernel_traced_seed():
     yr = ops.ota_project(x, seed=ref.splitmix32(jnp.uint32(3)), s_block=32,
                          rademacher=True, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(yk), np.asarray(yr))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("nb", [3, 7])
+def test_blocked_projection_matches_oracle(nb, use_kernel):
+    """The one blocked projection of the flat and slice paths, with a traced
+    seed: the oracle itself up to ``chunk_blocks`` blocks, the chunked scan
+    past them (7 blocks in chunks of 3, the last one padded), the kernel on
+    either side; every path bitwise the oracle's (Rademacher A)."""
+    x = jax.random.normal(jax.random.PRNGKey(nb), (nb, 128), jnp.float32)
+    seed = ref.splitmix32(jnp.uint32(3))
+
+    @jax.jit
+    def run(x, seed):
+        return blocked_projection(x, seed, 32, 3, rademacher=True,
+                                  use_kernel=use_kernel)
+
+    want = ref.ota_project_ref(x, seed, 32, rademacher=True)
+    np.testing.assert_array_equal(np.asarray(run(x, seed)), np.asarray(want))
 
 
 def test_projection_kernel_nb_tile_padding():
@@ -80,7 +103,8 @@ def test_fused_amp_matches_blocked_scan():
                             rademacher=True)
     x = _block_sparse_signal(d, c, sb)
     yb = proj.project(x).reshape(proj.n_blocks, sb)
-    x_scan = amp_decode_blocked_scan(yb, proj, iters=20)
+    x_scan = proj.from_blocks(amp_blocked_core(
+        yb, proj.seed, c, iters=20, chunk_blocks=proj.chunk_blocks))
     xb_fused = amp_blocked_core(yb, proj.seed, c, iters=20, chunk_blocks=4,
                                 use_kernel=True)
     np.testing.assert_allclose(np.asarray(proj.from_blocks(xb_fused)),
@@ -255,7 +279,7 @@ def test_amp_generator_invocations(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# end-to-end: the sharded slice driver honours use_kernel
+# end-to-end: the sharded slice driver honours OTAConfig.use_kernel
 # ---------------------------------------------------------------------------
 
 
@@ -281,7 +305,7 @@ def test_sharded_round_kernel_path_matches_jnp():
                         use_kernel=uk)
         sch = get_scheme(cfg, D, n_dev)
         ctx = MACContext(m=n_dev, device_axes=("dev",), d_pad=D,
-                         chunk_blocks=4, use_kernel=uk)
+                         chunk_blocks=4)
 
         def body(g, dl):
             ghat, nd, _ = distributed.sharded_round(

@@ -204,14 +204,14 @@ def test_m_active_ideal_mask_equals_true_subset(data):
 
 
 def test_use_kernel_runs_inside_scan(data):
-    """MACContext.use_kernel routes the blocked projection + fused AMP
+    """OTAConfig.use_kernel routes the blocked projection + fused AMP
     through Pallas (interpret mode off-TPU) inside the scanned loop."""
     (xd, yd), (xte, yte) = data
     cfg = _adsgd(projection="blocked", block_size=64, amp_iters=4)
-    jnp_run = run_compiled(xd, yd, xte, yte, cfg, steps=3, eval_every=1,
-                           use_kernel=False)
-    krn_run = run_compiled(xd, yd, xte, yte, cfg, steps=3, eval_every=1,
-                           use_kernel=True)
+    jnp_run = run_compiled(xd, yd, xte, yte, cfg, steps=3, eval_every=1)
+    krn_run = run_compiled(xd, yd, xte, yte,
+                           dataclasses.replace(cfg, use_kernel=True),
+                           steps=3, eval_every=1)
     np.testing.assert_allclose(jnp_run.all_accs, krn_run.all_accs, atol=1e-3)
 
 

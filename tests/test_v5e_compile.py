@@ -16,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 import repro.core  # noqa: F401  (kernels.amp_fused imports core.amp)
 from repro.kernels.amp_fused import amp_decode_fused_pallas
 from repro.kernels.ef_sparsify import ef_sparsify_pallas
-from repro.kernels.ota_project import ota_project_pallas, ota_project_t_pallas
+from repro.kernels.ota_project import ota_project_pallas
 
 C, S_BLOCK, CHUNK = 4096, 1024, 1 << 18
 N_BLOCKS = CHUNK // C
@@ -47,9 +47,6 @@ def _cases():
         "ota_project_stacked": (
             lambda x, sd: ota_project_pallas(x, sd, S_BLOCK, interpret=False),
             [((2, N_BLOCKS, C), jnp.float32), seed]),
-        "ota_project_t": (
-            lambda y, sd: ota_project_t_pallas(y, sd, C, interpret=False),
-            [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
         "amp_decode_fused": (
             lambda y, sd: amp_decode_fused_pallas(y, sd, C, iters=20,
                                                   interpret=False),
@@ -83,9 +80,6 @@ def _wrappers():
             lambda x, sd: ops.ota_project(x, seed=sd, s_block=S_BLOCK,
                                           use_kernel=True),
             [((N_BLOCKS, C), jnp.float32), seed]),
-        "ota_project_t": (
-            lambda y, sd: ops.ota_project_t(y, seed=sd, c=C, use_kernel=True),
-            [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
         "amp_decode_fused": (
             lambda y, sd: ops.amp_decode_fused(y, seed=sd, c=C, iters=2),
             [((N_BLOCKS, S_BLOCK), jnp.float32), seed]),
