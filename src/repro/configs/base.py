@@ -173,7 +173,7 @@ class OTAConfig:
     projection: str = "dense"  # dense (paper) | blocked (TPU framework path)
     block_size: int = 4096     # c — chunk of the flattened gradient (blocked path)
     rademacher: bool = False   # blocked path: ±1/sqrt(s_c) entries (kernel-friendly)
-    use_kernel: bool = False   # route the blocked projection through Pallas
+    use_kernel: bool = False   # run the blocked codec's Pallas kernels
     # distribution
     num_groups: int = 0        # 0 => one OTA device per ('pod','data') coordinate
     state_dtype: str = "float32"   # error-accumulator dtype

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import ops
 from repro.tracing import stage
 
 # ---------------------------------------------------------------------------
@@ -39,11 +40,14 @@ def topk_threshold(v: jnp.ndarray, k: int) -> jnp.ndarray:
 
 @stage("threshold")
 def sampled_topk_threshold(v: jnp.ndarray, k: int, key: jnp.ndarray,
-                           n_samples: int = 1 << 16) -> jnp.ndarray:
+                           n_samples: int = 1 << 16, *,
+                           use_kernel: bool = False) -> jnp.ndarray:
     """Approximate k-th largest |v| from a strided sample (framework scale).
 
     Strided sampling (start offset from the key) instead of random gather:
     indices stay int32-safe at d > 2^31 and the read is a cheap slice.  The
+    sample's quantile is :func:`repro.kernels.ops.sample_quantile`, found by
+    exact selection with ``use_kernel``, bitwise the sorted quantile.  The
     sparsifier then applies the threshold pointwise.
     """
     d = v.shape[-1]
@@ -55,7 +59,7 @@ def sampled_topk_threshold(v: jnp.ndarray, k: int, key: jnp.ndarray,
         sample = jnp.abs(jax.lax.slice_in_dim(v, 0, n * stride, stride,
                                               axis=-1))
     q = 1.0 - (k / d)
-    return jnp.quantile(sample, q, axis=-1)
+    return ops.sample_quantile(sample, q, use_kernel=use_kernel)
 
 
 def error_feedback(g: jnp.ndarray, delta: jnp.ndarray) -> jnp.ndarray:

@@ -499,7 +499,8 @@ class ADSGDScheme(Scheme):
             g_sp = compression.top_k_sparsify(g_ec, self.k)
             new_state = g_ec - g_sp
         else:
-            tau = compression.sampled_topk_threshold(g_ec, self.k, key)
+            tau = compression.sampled_topk_threshold(
+                g_ec, self.k, key, use_kernel=cfg.use_kernel)
             g_sp, new_state = ops.ef_sparsify(
                 g, state.astype(jnp.float32), tau, use_kernel=cfg.use_kernel)
         g_tilde = projector.project(g_sp)
@@ -551,7 +552,8 @@ class ADSGDScheme(Scheme):
                                               ctx.shard_axes).reshape(-1)
                            if ctx.shard_axes else local_sample)
             q = 1.0 - k / d_pad
-            tau = jnp.quantile(all_samples, q)
+            tau = ops.sample_quantile(all_samples, q,
+                                      use_kernel=cfg.use_kernel)
         keep = jnp.abs(g_ec) >= tau
         g_sp = jnp.where(keep, g_ec, 0.0)
         new_state = (g_ec - g_sp).astype(state_slice.dtype)

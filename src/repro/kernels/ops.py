@@ -18,11 +18,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 from repro.kernels import ref
 from repro.kernels.amp_fused import amp_decode_fused_pallas
 from repro.kernels.ef_sparsify import ef_sparsify_pallas
 from repro.kernels.ota_project import ota_project_pallas
+from repro.kernels.quantile_select import quantile_select_pallas
 
 
 def interpret_default() -> bool:
@@ -34,7 +37,9 @@ def interpret_default() -> bool:
 
 #: Lowerings of the kernel projection under ``vmap``: ``shared`` stacks
 #: whose devices share each generated A tile, the ``devices`` they cover,
-#: and ``fallback`` vmaps of a batched seed (one A per device).
+#: and ``fallback`` vmaps of a batched seed (one A per device); of
+#: :func:`sample_quantile`: ``select`` through the ``quantile_select``
+#: kernel, ``sort`` through ``jnp.quantile``.
 _LOWERINGS = collections.Counter()
 
 
@@ -117,3 +122,44 @@ def ef_sparsify(g: jnp.ndarray, delta: jnp.ndarray, tau, *,
         return ef_sparsify_pallas(g, delta, jnp.asarray(tau),
                                   interpret=interpret_default())
     return ref.ef_sparsify_ref(g, delta, jnp.asarray(tau))
+
+
+def quantile_lowerings() -> dict:
+    """A copy of the sampled quantile's lowering counts (``_LOWERINGS``)."""
+    return {k: _LOWERINGS[k] for k in ("select", "sort")}
+
+
+@functools.partial(jax.jit, static_argnames=("q", "use_kernel"))
+def sample_quantile(sample: jnp.ndarray, q: float, *,
+                    use_kernel: bool = False) -> jnp.ndarray:
+    """``jnp.quantile(sample, q, axis=-1)`` (linear) of a non-negative
+    float32 sample (..., n), bitwise.
+
+    ``use_kernel`` finds the two order statistics it interpolates by exact
+    selection (kernels/quantile_select.py) instead of a sort.  Their ranks
+    and weights are jnp's, computed as it computes them, and the weights
+    combine the two values here, inside this jit, in jnp's own expression:
+    the same lax ops, so the same rounding.  A ``vmap`` over devices runs
+    one kernel launch for the stack."""
+    if not use_kernel:
+        _LOWERINGS["sort"] += 1
+        return jnp.quantile(sample, q, axis=-1)
+    _LOWERINGS["select"] += 1
+    n = sample.shape[-1]
+    # jnp.quantile's ranks: f32(q) * (f32(n) - 1), floored and ceiled,
+    # clamped to [0, f32(n) - 1]
+    last = np.float32(n) - np.float32(1)
+    pos = np.float32(q) * last
+    lo_rank, hi_rank = (int(np.clip(r, 0, last))
+                        for r in (np.floor(pos), np.ceil(pos)))
+    low_value, high_value = quantile_select_pallas(
+        sample.reshape(-1, n), lo_rank, hi_rank,
+        interpret=interpret_default())
+    # ... and its weights and interpolation (method="linear"), op for op
+    qa = jnp.asarray(q)
+    pos = lax.mul(qa, lax.convert_element_type(n, qa.dtype) - 1)
+    high_weight = lax.sub(pos, lax.floor(pos))
+    low_weight = lax.sub(np.ones((), high_weight.dtype), high_weight)
+    tau = lax.add(lax.mul(low_value, low_weight),
+                  lax.mul(high_value, high_weight))
+    return tau.reshape(sample.shape[:-1])

@@ -14,12 +14,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 import repro.core  # noqa: F401  (kernels.amp_fused imports core.amp)
+from repro.kernels import ops
 from repro.kernels.amp_fused import amp_decode_fused_pallas
 from repro.kernels.ef_sparsify import ef_sparsify_pallas
 from repro.kernels.ota_project import ota_project_pallas
+from repro.kernels.quantile_select import quantile_select_pallas
 
 C, S_BLOCK, CHUNK = 4096, 1024, 1 << 18
 N_BLOCKS = CHUNK // C
+#: the sampled threshold's sample and its ranks at q = 1 - k/d = 0.875
+N_SAMPLES, LO_RANK = 1 << 16, 57343
 
 
 @pytest.fixture(scope="module")
@@ -60,11 +64,23 @@ def _cases():
                                                           interpret=False)),
             [((2, CHUNK), jnp.float32), ((2, CHUNK), jnp.float32),
              ((2,), jnp.float32)]),
+        # both devices' threshold samples of one streamed chunk
+        "quantile_select": (
+            lambda s: quantile_select_pallas(s, LO_RANK, LO_RANK + 1,
+                                             interpret=False),
+            [((2, N_SAMPLES), jnp.float32)]),
+        # the wrapper the streamed encode vmaps over its devices
+        "sample_quantile_vmapped": (
+            jax.vmap(lambda s: ops.sample_quantile(s, 0.875,
+                                                   use_kernel=True)),
+            [((2, N_SAMPLES), jnp.float32)]),
     }
 
 
 @pytest.mark.parametrize("name", sorted(_cases()))
-def test_kernel_compiles_for_v5e(name, one_chip):
+def test_kernel_compiles_for_v5e(name, one_chip, monkeypatch):
+    monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    jax.clear_caches()      # no wrapper trace cached in interpret mode
     fn, specs = _cases()[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
@@ -73,7 +89,6 @@ def test_kernel_compiles_for_v5e(name, one_chip):
 
 
 def _wrappers():
-    from repro.kernels import ops
     seed = ((), jnp.uint32)
     return {
         "ota_project": (
@@ -86,14 +101,19 @@ def _wrappers():
         "ef_sparsify": (
             lambda g, d: ops.ef_sparsify(g, d, 0.5, use_kernel=True),
             [((CHUNK,), jnp.float32), ((CHUNK,), jnp.float32)]),
+        # vmapped over the devices, as the streamed encode calls it
+        "quantile_select": (
+            jax.vmap(lambda s: ops.sample_quantile(s, 0.875,
+                                                   use_kernel=True)),
+            [((2, N_SAMPLES), jnp.float32)]),
     }
 
 
 @pytest.mark.parametrize("name", sorted(_wrappers()))
 def test_kernel_wrapper_names_its_kernel(name, one_chip, monkeypatch):
     """Each Pallas kernel is named, so a profile shows ``<name>.<n>``."""
-    from repro.kernels import ops
     monkeypatch.setattr(ops, "interpret_default", lambda: False)
+    jax.clear_caches()
     fn, specs = _wrappers()[name]
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in specs]
